@@ -17,6 +17,7 @@ import sys
 import traceback
 
 from benchmarks.common import emit
+from repro.launch.runtime import device_info, device_tag, enable_compile_cache
 
 
 def _write_json(path: str, rows, argv, failed) -> str:
@@ -38,6 +39,7 @@ def _write_json(path: str, rows, argv, failed) -> str:
         "argv": list(argv) if argv is not None else sys.argv[1:],
         "host": {"platform": platform.platform(),
                  "python": platform.python_version()},
+        "device": device_info(),
         "failed_suites": failed,
         "rows": [{"name": n, "us_per_call": us, "derived": d}
                  for n, us, d in rows],
@@ -154,6 +156,9 @@ def main(argv=None) -> int:
             traceback.print_exc()
             failed.append("phase")
             rc = 1
+    # every rate below was measured on this device (a CPU run is never a
+    # chip number)
+    print(f"# device {device_tag()}")
     emit(rows)
     if args.json is not None:
         path = _write_json(args.json, rows, argv, failed)
@@ -162,4 +167,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

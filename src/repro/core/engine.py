@@ -359,8 +359,12 @@ class _Executable:
             # the meet-in-the-middle breakpoint solver: backends may ship a
             # fused meet variant (the kernel runs both fronts' rings in
             # VMEM with per-block early exit); otherwise the shared jnp
-            # solver serves every backend
-            backend_fn = spec.meet_variant or wf.wfa_bidir_meet
+            # solver serves every backend.  The fused meet kernel's
+            # per-pair gathers do not lower for the TPU, so there the jnp
+            # solver serves every backend too.
+            fused = (spec.meet_variant is not None
+                     and jax.default_backend() != "tpu")
+            backend_fn = spec.meet_variant if fused else wf.wfa_bidir_meet
             self._dispatch = None
             extra = {}
         else:
@@ -511,8 +515,10 @@ class AlignmentEngine:
         # bidir recursion base case: packed traceback allowed when a
         # sub-problem's s*(plen+tlen) fits this many cells (None = default)
         self.trace_budget = trace_budget
+        # waves pad to a multiple of the devices they are split over: the
+        # mesh's, or the one default device a mesh-less engine places on
         self.n_workers = (int(np.prod(list(mesh.shape.values())))
-                          if mesh is not None else jax.device_count())
+                          if mesh is not None else 1)
         self._cache: Dict[tuple, _Executable] = {}
 
     @property

@@ -1141,7 +1141,6 @@ def wfa_trace_shardmap(pattern, text, plen, tlen, *, pen,
     linear models.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     model = scoring.as_model(pen)
     names = tuple(axis_names if axis_names is not None else mesh.axis_names)
@@ -1165,12 +1164,9 @@ def wfa_trace_shardmap(pattern, text, plen, tlen, *, pen,
 
         out_specs = (spec1, spec_bt)
 
-    kwargs = dict(mesh=mesh, in_specs=(spec2, spec2, spec1, spec1),
-                  out_specs=out_specs)
-    try:
-        fn = shard_map(local, check_rep=False, **kwargs)
-    except TypeError:  # newer jax dropped the check_rep kwarg
-        fn = shard_map(local, **kwargs)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(spec2, spec2, spec1, spec1),
+                       out_specs=out_specs, check_vma=False)
     out = fn(pattern, text, plen, tlen)
     if affine:
         return out
@@ -1190,7 +1186,6 @@ def wfa_scores_shardmap(pattern, text, plen, tlen, *, pen,
     the lowered HLO (asserted by tests) and per-shard early exit.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     names = tuple(axis_names if axis_names is not None else mesh.axis_names)
     spec2 = P(names, None)
@@ -1200,12 +1195,9 @@ def wfa_scores_shardmap(pattern, text, plen, tlen, *, pen,
         return wfa_scores(p, t, pl, tl, pen=pen, s_max=s_max,
                           k_max=k_max, heur=heur, band_cap=band_cap).score
 
-    kwargs = dict(mesh=mesh, in_specs=(spec2, spec2, spec1, spec1),
-                  out_specs=spec1)
-    try:
-        # older jax has no replication rule for while_loop; the per-shard
-        # score loop is replication-safe by construction, so opt out
-        fn = shard_map(local, check_rep=False, **kwargs)
-    except TypeError:  # newer jax dropped the check_rep kwarg
-        fn = shard_map(local, **kwargs)
+    # the per-shard score loop is replication-safe by construction, so
+    # the varying-manual-axes check is off
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(spec2, spec2, spec1, spec1),
+                       out_specs=spec1, check_vma=False)
     return fn(pattern, text, plen, tlen)

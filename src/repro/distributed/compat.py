@@ -1,54 +1,20 @@
-"""Version compatibility shims for the jax sharding API.
+"""Mesh construction with explicit axis types.
 
-The codebase targets the modern mesh API (``jax.make_mesh(...,
-axis_types=(AxisType.Auto, ...))``), but CI images pin older jax releases
-(0.4.x) where ``jax.sharding.AxisType`` does not exist and ``make_mesh``
-rejects the ``axis_types`` kwarg.  Everything that builds a mesh —
-``launch.mesh``, the distributed sharding/fault tests, ad-hoc scripts —
-goes through :func:`make_mesh` here so the rest of the tree never touches
-the moving part of the API directly.
+Everything that builds a mesh — ``launch.mesh``, the distributed
+sharding/fault tests, ad-hoc scripts — goes through :func:`make_mesh`, so
+every mesh in the tree carries ``AxisType.Auto`` axes.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Sequence
 
 import jax
-from jax.sharding import Mesh
-
-
-def auto_axis_types(n: int) -> Optional[Tuple]:
-    """``(AxisType.Auto,) * n`` on jax versions that have it, else None."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n
-
-
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a dict on every jax version.
-
-    Older jax returns a one-element list of per-program dicts; newer jax
-    returns the dict directly.  Either way an empty result becomes ``{}``.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
               devices=None) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported.
-
-    Falls back to the positional-only signature on jax versions whose
-    ``make_mesh`` predates the ``axis_types`` kwarg.
-    """
+    """``jax.make_mesh`` with Auto axis types."""
     kwargs = {} if devices is None else {"devices": devices}
-    types = auto_axis_types(len(shape))
-    if types is not None:
-        try:
-            return jax.make_mesh(tuple(shape), tuple(axes),
-                                 axis_types=types, **kwargs)
-        except TypeError:
-            pass  # old make_mesh: no axis_types kwarg
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape), **kwargs)

@@ -76,6 +76,22 @@ from repro.core.wavefront import (BT_GAP_EXT, BT_GAP_OPEN, BT_M_FROM_D,
 NEG = -(1 << 20)
 _THRESH = NEG // 2
 
+# The TPU compiler's default scoped-VMEM limit for one kernel.
+SCOPED_VMEM_BYTES = 16 << 20
+
+
+def vmem_estimate(block_pairs: int, k_pad: int, n_bt: int,
+                  n_words: int) -> int:
+    """Scoped VMEM bytes a compiled block needs, calibrated on v5e compiles.
+
+    About 4.2 KB per (pair, diagonal lane) for the rings, the [BP, K]
+    fronts and the onehot fetch, plus the double-buffered packed-trace
+    output blocks (``n_bt`` planes of ``n_words`` int32 words).  At 8 pairs
+    per block that admits k_pad <= 384 at every bucket width from 128 to
+    1024, and trace depth up to s_max = 704 at k_pad = 384.
+    """
+    return block_pairs * k_pad * (4332 + 8 * n_bt * n_words)
+
 
 def _gather_chars(seq, idx, mode: str):
     """seq [BP, L], idx [BP, K] -> seq[b, idx[b, k]] as [BP, K].
@@ -191,20 +207,25 @@ def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
             row = lax.rem(jnp.maximum(s - delta, 0), W)
             val = ring[pl.ds(row, 1)][0]
             if band:
-                # realign the stored window to the current offset: pad both
-                # sides with NEG and slide by the offset delta (no gather)
+                # realign the stored window to the current offset:
+                # val'[j] = val[j + shift], NEG where j + shift leaves the
+                # window (a lane rotation plus a mask — no gather, no
+                # dynamic slice, both of which Mosaic refuses)
                 shift = jnp.clip(off - off_ref[row, 0], -Kc, Kc)
-                padded = jnp.concatenate([neg_kc, val, neg_kc], axis=1)
-                val = lax.dynamic_slice_in_dim(padded, Kc + shift, Kc,
-                                               axis=1)
+                rolled = pltpu.roll(val, lax.rem(Kc - shift, Kc), 1)
+                src = jidx + shift
+                val = jnp.where((src >= 0) & (src < Kc), rolled, NEG)
             return jnp.where(s >= delta, val, NEG)
 
         def scatter_full(code, off):
-            """Place a compact [BP, Kc] plane at absolute k (width k_pad)."""
+            """Place a compact [BP, Kc] plane at absolute k (width k_pad):
+            zero-extend to k_pad lanes and rotate by ``off`` (the clip in
+            ``recenter`` keeps off + Kc <= k_pad, so only zeros wrap)."""
             if not band:
                 return code
-            full = jnp.zeros((BP, k_pad), jnp.int32)
-            return lax.dynamic_update_slice(full, code, (0, off))
+            full = jnp.concatenate(
+                [code, jnp.zeros((BP, k_pad - Kc), jnp.int32)], axis=1)
+            return pltpu.roll(full, off, 1)
 
         def pack_code(bt_ref, s, code, off):
             """OR the 2-bit code plane into word s//16 of bt_ref."""
@@ -221,7 +242,8 @@ def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
                 bt[...] = jnp.zeros_like(bt)
         if band:
             off0 = min(max(kc_full - Kc // 2, 0), k_pad - Kc)
-            off_ref[...] = jnp.full(off_ref.shape, off0, jnp.int32)
+            for w in range(W):               # SMEM takes scalar stores only
+                off_ref[w, 0] = jnp.int32(off0)
         else:
             off0 = 0
         ks0 = ks_of(off0)
@@ -356,6 +378,16 @@ def wfa_pallas(pattern, text, plen, tlen, *, pen, s_max: int,
         gather = "index" if interpret else "onehot"
     band = band_cap is not None and band_cap < k_pad
     Kc = band_cap if band else k_pad
+    n_bt = (3 if model.kind == "affine" else 1) if trace else 0
+    if not interpret:
+        need = vmem_estimate(BP, k_pad, n_bt, n_trace_words(s_max))
+        if need > SCOPED_VMEM_BYTES:
+            raise ValueError(
+                f"WFA kernel block too wide for the TPU's scoped VMEM: "
+                f"block_pairs={BP}, k_pad={k_pad}, s_max={s_max}, "
+                f"trace={trace} needs ~{need / 2**20:.1f} MiB of "
+                f"{SCOPED_VMEM_BYTES >> 20} MiB (bucket [{B}, {Lp}]); "
+                f"align this bucket on the 'ring' backend")
     kernel, W, affine = _make_kernel(model, heur, s_max, k_pad, trace,
                                      gather, max(int(ext_stride), 1), band)
     grid = (B // BP,)
@@ -367,7 +399,6 @@ def wfa_pallas(pattern, text, plen, tlen, *, pen, s_max: int,
                  jax.ShapeDtypeStruct((B, 1), jnp.int32)]
     if trace:
         NW = n_trace_words(s_max)
-        n_bt = 3 if affine else 1
         bt_spec = pl.BlockSpec((NW, BP, k_pad), lambda i: (0, i, 0))
         out_specs += [bt_spec] * n_bt
         out_shape += [jax.ShapeDtypeStruct((NW, B, k_pad), jnp.int32)] * n_bt

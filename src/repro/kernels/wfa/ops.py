@@ -47,6 +47,11 @@ def _pad_axis(x, axis: int, to: int, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
+def default_interpret() -> bool:
+    """Interpret mode off the TPU (CPU validation), compiled on it."""
+    return jax.default_backend() != "tpu"
+
+
 def resolve_block_pairs(block_pairs: Optional[int]) -> int:
     """Auto-default for pairs-per-grid-program (one int32 sublane tile)."""
     if block_pairs is None:
@@ -97,7 +102,7 @@ def wfa_align(pattern, text, plen, tlen, *, pen, s_max: int,
     TPU; the remaining knobs are documented in the module docstring.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     bp = resolve_block_pairs(block_pairs)
     pattern, text, plen2, tlen2, B = _prep(pattern, text, plen, tlen, bp)
     k_pad = _round_up(2 * k_max + 1, LANE)
@@ -126,7 +131,7 @@ def wfa_align_trace(pattern, text, plen, tlen, *, pen, s_max: int,
     Linear penalty models record a single M plane: ``i_bt = d_bt = None``.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     bp = resolve_block_pairs(block_pairs)
     pattern, text, plen2, tlen2, B = _prep(pattern, text, plen, tlen, bp)
     k_pad = _round_up(2 * k_max + 1, LANE)
@@ -159,21 +164,21 @@ def wfa_bidir_meet_kernel(pattern, text, plen, tlen, starget, *, pen,
     ``trace_variant="bidir"`` meet waves: both fronts' rings live in VMEM
     scratch and each grid program exits as soon as its own block's pairs
     have met, instead of the jnp solver's whole-batch early-exit.  The
-    meet detector's per-pair ring reads are real gathers, so the fused
-    grid is interpret-mode only for now — compiled TPU runs delegate to
-    the jnp solver (same results, already fully jitted).
+    meet detector's per-pair ring reads are real gathers, which Mosaic
+    refuses ("Only 2D gather is supported"), so the fused grid runs in
+    interpret mode only; asking for a compiled run raises.  The engine
+    picks the jnp solver for meet waves on the TPU.
     """
     from repro.core.wavefront import BidirMeetResult, _reverse_rows
-    from repro.core import wavefront as _wf
     from repro.kernels.wfa.kernel import wfa_meet_pallas
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     if not interpret:
-        return _wf.wfa_bidir_meet(pattern, text, plen, tlen, starget,
-                                  pen=pen, s_max=s_max, k_max=k_max,
-                                  heur=heur, begin_state=begin_state,
-                                  end_state=end_state)
+        raise NotImplementedError(
+            "the fused BiWFA meet kernel does not compile for the TPU: its "
+            "per-pair ring reads are gathers Mosaic cannot lower; use "
+            "core.wavefront.wfa_bidir_meet there")
     bp = resolve_block_pairs(block_pairs)
     pattern2, text2, plen2, tlen2, B = _prep(pattern, text, plen, tlen, bp)
     starget2 = _pad_axis(

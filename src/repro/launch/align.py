@@ -40,6 +40,7 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -53,6 +54,7 @@ from repro.core.gotoh import gotoh_score_vec, score_cigar
 from repro.core.session import run_streamed
 from repro.data.io import load_pair_files
 from repro.data.reads import ReadPairSpec, generate_pairs
+from repro.launch.runtime import device_tag, enable_compile_cache
 
 
 def _run_sync(engine, P, plen, T, tlen, output):
@@ -89,7 +91,12 @@ def write_sam(out, scores, cigars, plen, T, tlen, cl=None) -> None:
         out.write(line + "\n")
 
 
-def main(argv=None):
+def main(argv=None) -> int:
+    return run(argv)[0]
+
+
+def run(argv=None) -> Tuple[int, Optional[np.ndarray]]:
+    """The launcher's work -> (exit code, per-pair scores or None)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--pairs", type=int, default=4096)
     ap.add_argument("--read-len", type=int, default=wfa_paper.read_len)
@@ -222,12 +229,13 @@ def main(argv=None):
         log(f"[align] trace -> {args.trace_out}")
 
     scores = cigars = None
+    device = device_tag()
     for mode, (sc, cg, st, wall) in runs:
         if scores is None:
             scores, cigars = sc, cg
         elif not np.array_equal(scores, sc):
             log("[align] ERROR: sync and stream scores differ")
-            return 1
+            return 1, None
         pim = st.pim
         extra = ""
         if mode == "stream":
@@ -243,9 +251,9 @@ def main(argv=None):
               f"kernel {pim.t_kernel:.3f}s  gather {pim.t_gather:.3f}s  "
               f"wall {wall:.3f}s")
         log(f"[align] {mode}: throughput Total  = "
-              f"{args.pairs / wall:,.0f} pairs/s")
+              f"{args.pairs / wall:,.0f} pairs/s {device}")
         log(f"[align] {mode}: throughput Kernel = "
-              f"{pim.throughput_kernel():,.0f} pairs/s")
+              f"{pim.throughput_kernel():,.0f} pairs/s {device}")
         log(f"[align] {mode}: transfers: {pim.bytes_in / 1e6:.1f} MB in, "
               f"{pim.bytes_out / 1e6:.3f} MB out")
         found = sc >= 0
@@ -280,6 +288,11 @@ def main(argv=None):
     if args.verify:
         n = min(args.verify, args.pairs)
         pen_triple = pen.as_penalties()
+        n_unresolved = int((scores < 0).sum())
+        if heur.exact and not args.no_adaptive and n_unresolved:
+            # exact bounds with recovery resolve every pair by construction
+            log(f"[align] UNRESOLVED: {n_unresolved} pairs have no score")
+            return 1, scores
         for i in range(n):
             pa, ta = P[i, : plen[i]], T[i, : tlen[i]]
             g = gotoh_score_vec(pa, ta, pen_triple)
@@ -287,7 +300,7 @@ def main(argv=None):
             bad = (scores[i] != g if heur.exact else scores[i] < g)
             if scores[i] >= 0 and bad:
                 log(f"[align] MISMATCH pair {i}: wfa={scores[i]} gotoh={g}")
-                return 1
+                return 1, scores
             if cigars is not None and scores[i] >= 0:
                 cost, ci, cj, ok = score_cigar(cigars[i], pa, ta, pen_triple)
                 # the CIGAR must re-score to the reported (possibly
@@ -295,13 +308,14 @@ def main(argv=None):
                 if not ok or cost != scores[i]:
                     log(f"[align] CIGAR MISMATCH pair {i}: "
                           f"re-score={cost} wfa={scores[i]} ok={ok}")
-                    return 1
+                    return 1, scores
         what = "scores + CIGARs" if cigars is not None else "scores"
         against = ("Gotoh oracle" if heur.exact
                    else "Gotoh oracle (upper-bound check: heuristic)")
         log(f"[align] verified {n} {what} against {against}")
-    return 0
+    return 0, scores
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

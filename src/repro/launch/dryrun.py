@@ -15,7 +15,6 @@ import numpy as np
 
 from repro.analysis.hlo import collective_bytes
 from repro.configs import ARCH_NAMES, get_config, wfa_paper
-from repro.distributed.compat import cost_analysis
 from repro.launch.lowering import build_lm_cell, build_wfa_cell, lower_cell
 from repro.launch.mesh import make_production_mesh, mesh_devices
 from repro.models.common import SHAPES, model_flops
@@ -56,7 +55,7 @@ def _compile_and_measure(cell, mesh, n_dev) -> dict:
     t2 = time.time()
 
     out = {"lower_s": round(t1 - t0, 2), "compile_s": round(t2 - t1, 2)}
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     out["flops_per_device"] = float(cost.get("flops", -1.0))
     out["bytes_per_device"] = float(cost.get("bytes accessed", -1.0))
     try:

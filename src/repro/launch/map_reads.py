@@ -27,6 +27,7 @@ from repro.core import scoring
 from repro.core.backends import available_backends, get_backend
 from repro.core.engine import AlignmentEngine
 from repro.data.io import read_seqs
+from repro.launch.runtime import device_tag, enable_compile_cache
 from repro.mapping.extend import ReadMapper, suggested_edit_frac
 from repro.mapping.index import MinimizerIndex
 from repro.mapping.sam import write_sam
@@ -170,9 +171,10 @@ def main(argv=None):
         + ("" if sam_to_stdout else f" in {args.sam_out}"))
     log(f"[map] throughput: {st.n_reads / max(wall, 1e-9):,.0f} reads/s "
         f"({st.n_extensions / max(wall, 1e-9):,.0f} extensions/s), "
-        f"wall {wall:.3f}s")
+        f"wall {wall:.3f}s {device_tag()}")
     return 0
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -25,6 +25,7 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -32,10 +33,16 @@ from repro import obs
 from repro.core import scoring
 from repro.core.engine import AlignmentEngine
 from repro.data.reads import ArrivalSpec, generate_trace
-from repro.serve import ServeLoop, replay_trace
+from repro.launch.runtime import device_tag, enable_compile_cache
+from repro.serve import ReplayReport, ServeLoop, replay_trace
 
 
 def main(argv=None) -> int:
+    return run(argv)[0]
+
+
+def run(argv=None) -> Tuple[int, Optional[ReplayReport]]:
+    """The launcher's work -> (exit code, the measured replay's report)."""
     ap = argparse.ArgumentParser(
         description="open-loop driver for the always-on alignment service")
     ap.add_argument("--requests", type=int, default=512)
@@ -92,7 +99,7 @@ def main(argv=None) -> int:
             msrv.shutdown()
 
 
-def _run(args) -> int:
+def _run(args) -> Tuple[int, ReplayReport]:
 
     pen = (scoring.parse_penalties(args.penalties)
            if args.penalties else None)
@@ -117,8 +124,9 @@ def _run(args) -> int:
         eng.align_packed(P, plen, T, tlen, penalties=pen, heuristic=heur)
         batch_pps = len(plen) / (time.perf_counter() - t0)
         rate = args.load * batch_pps / args.pairs_per_request
-        print(f"[serve_align] batch mode: {batch_pps:,.0f} pairs/s -> "
-              f"offered {rate:,.0f} req/s ({args.load:.0%} load)",
+        print(f"[serve_align] batch mode: {batch_pps:,.0f} pairs/s "
+              f"{device_tag()} -> offered {rate:,.0f} req/s "
+              f"({args.load:.0%} load)",
               file=sys.stderr)
 
     # warm the serving wave shape so the replay is steady-state
@@ -155,7 +163,7 @@ def _run(args) -> int:
           f"{report.n_shed} shed, {report.n_failed} failed "
           f"(driver lag max {report.lag_max * 1e3:.1f} ms)")
     print(f"[serve_align] sustained {report.sustained_pairs_per_s:,.0f} "
-          f"pairs/s over {report.t_sustained:.2f}s")
+          f"pairs/s over {report.t_sustained:.2f}s {device_tag()}")
     print(f"[serve_align] latency p50 {report.percentile_ms(50):.1f} ms | "
           f"p95 {report.percentile_ms(95):.1f} ms | "
           f"p99 {report.percentile_ms(99):.1f} ms "
@@ -167,8 +175,9 @@ def _run(args) -> int:
     print(f"[serve_align] executable cache: {st.cache_hits} hits, "
           f"{st.cache_misses} misses, "
           f"{eng.cache_traces() - traces0} fresh traces during replay")
-    return 0 if report.n_failed == 0 else 1
+    return (0 if report.n_failed == 0 else 1), report
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -17,7 +17,6 @@ import json
 import jax
 from repro.analysis.hlo import collective_bytes
 from repro.configs import smoke_config, wfa_paper
-from repro.distributed.compat import cost_analysis
 from repro.launch.lowering import build_lm_cell, build_wfa_cell, lower_cell
 from repro.launch.mesh import make_mesh
 from repro.models.common import ShapeSpec
@@ -33,7 +32,7 @@ for arch, shape in [("qwen3-0.6b", ShapeSpec("t", 64, 8, "train")),
     cell = build_lm_cell(cfg, shape, mesh, mode="roofline")
     lowered, _ = lower_cell(cell, mesh)
     compiled = lowered.compile()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     out[f"{arch}:{shape.kind}"] = {
         "flops": float(cost.get("flops", -1)),
         "coll": collective_bytes(compiled.as_text(), 16)["total"],

@@ -203,6 +203,16 @@ def test_meet_kernel_block_invariance():
             err_msg=field)
 
 
+def test_meet_kernel_refuses_compiled_mode():
+    """The fused meet kernel never hands a compiled request to another
+    solver: it raises, and the engine picks the jnp solver on the TPU."""
+    P, plen, T, tlen, s_max, k_max = _pairs(4, 32, 0.08, 29)
+    with pytest.raises(NotImplementedError, match="does not compile"):
+        kops.wfa_bidir_meet_kernel(P, T, plen, tlen, np.zeros(4, np.int32),
+                                   pen=DEFAULT, s_max=s_max, k_max=k_max,
+                                   interpret=False)
+
+
 # -- engine backend_opts plumbing -------------------------------------------
 
 

@@ -182,8 +182,11 @@ def test_backend_runtime_failure_propagates(rng):
         pats, txts = _random_pairs(rng, 4, lo=20, hi=40)
         eng = AlignmentEngine(backend="boom", edit_frac=0.05)
         sess = eng.stream(max_inflight_waves=2)
-        sess.submit(pats, txts)      # dispatch succeeds; failure is async
-        with pytest.raises(Exception):
+        # the injected failure surfaces at submit (when the runtime raises
+        # it while dispatching) or at drain (when it arrives with the
+        # result); either way the session re-raises it
+        with pytest.raises(Exception, match="injected backend failure"):
+            sess.submit(pats, txts)
             sess.drain()
         # the session is poisoned: no further submissions accepted
         with pytest.raises(RuntimeError, match="session failed"):
