@@ -14,7 +14,11 @@ negligible:
   streamed measurement and the projection both run with the post-mortem
   ring active (``obs/flightrec_ns`` is the ring's full span cycle), so
   the 2% budget covers the always-on configuration a live server
-  actually runs in, not just the bare branch.  Gate: <= 2%.
+  actually runs in, not just the bare branch.  Both are timed with the
+  ``jax.profiler`` bridge inactive (``obs.profile._active`` false), the
+  branch every span takes outside a ``--profile`` capture; while one is
+  active each span also opens a profiler annotation, which
+  ``obs/annotated_ns`` times.  Gate: <= 2%.
 * **enabled** — spans, flow events and counters are actually buffered.
   ``obs/on_ratio`` is enabled/disabled align throughput (warm engine,
   best-of-3 each, interleaved).  Gate: >= 0.90 — capturing a timeline
@@ -38,6 +42,7 @@ from repro.core.engine import AlignmentEngine
 from repro.core.session import run_streamed
 from repro.data.reads import ReadPairSpec, generate_pairs
 from repro.obs import metrics as obs_metrics
+from repro.obs import profile as obs_profile
 from repro.obs import record as obs_record
 from repro.obs import trace as obs_trace
 
@@ -59,8 +64,9 @@ def _bench_stream(eng, P, plen, T, tlen, submit_pairs: int,
                   iters: int = 3) -> float:
     """Best-of-``iters`` wall seconds for one warm streamed pass.
 
-    The streamed session is the instrumented path (wave.scatter /
-    wave.kernel / wave.gather spans + per-ticket flows), so this is the
+    The streamed session is the instrumented path (wave.pack /
+    wave.dispatch / wave.wait / wave.gather spans + per-ticket flows),
+    so this is the
     surface the overhead gates actually protect.
     """
     best = float("inf")
@@ -89,8 +95,10 @@ def run(pairs: int = 4096, read_len: int = 100, edit_frac: float = 0.02,
                  submit_pairs=submit_pairs)          # warm the cache
 
     with obs_trace.isolated():
-        # "disabled" is the production default: tracer off, flight
-        # recorder ON (a live server keeps the post-mortem ring warm).
+        # "disabled" is the production default: tracer off, no profile
+        # capture, flight recorder ON (a live server keeps the
+        # post-mortem ring warm).
+        assert not obs_profile.active(), "measure outside --profile"
         obs_record.acquire()
         try:
             # interleaved off/on/off/on: shared-host noise hits both modes
@@ -123,8 +131,16 @@ def run(pairs: int = 4096, read_len: int = 100, edit_frac: float = 0.02,
             gauge_ns = _ns_per_call(lambda: g.set(1.0))
         finally:
             obs_record.release()
-        # bare branch cost: tracer off, recorder off -> NULL span
+        # bare branch cost: tracer off, recorder off, profile inactive
+        # -> NULL span
         span_ns = _ns_per_call(lambda: obs_trace.span("x"))
+        # profile active: the span opens a profiler annotation (no
+        # capture running, so this is the bridge's own cost)
+        obs_profile._active = True
+        try:
+            annotated_ns = _ns_per_call(_span_cycle, n=50_000)
+        finally:
+            obs_profile._active = False
 
     n_waves = max(1, -(-pairs // submit_pairs))
     wave_s = t_off / n_waves
@@ -147,6 +163,9 @@ def run(pairs: int = 4096, read_len: int = 100, edit_frac: float = 0.02,
         ("obs/flightrec_ns", rec_span_ns,
          "ns per full span cycle with tracing off + flight-recorder "
          "ring active"),
+        ("obs/annotated_ns", annotated_ns,
+         "ns per full span cycle with tracing off + profile active "
+         "(the span's jax.profiler annotation)"),
         ("obs/disabled_frac", disabled_frac,
          f"projected disabled overhead per wave: {CALLS_PER_WAVE} span "
          f"points x {worst_span_ns:.0f} ns (ring-active worst case) + "

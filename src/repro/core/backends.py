@@ -275,12 +275,12 @@ def _kernel_trace(pattern, text, plen, tlen, *, pen, s_max, k_max,
                   heur=None, block_pairs=None, gather=None, ext_stride=1,
                   band_cap=None):
     from repro.kernels.wfa import ops as kops  # lazy: pallas import is heavy
-    score, m_bt, i_bt, d_bt = kops.wfa_align_trace(
+    score, m_bt, i_bt, d_bt, steps, trips = kops.wfa_align_trace(
         pattern, text, plen, tlen, pen=pen, s_max=s_max, k_max=k_max,
         heur=heur, block_pairs=block_pairs, gather=gather,
-        ext_stride=ext_stride, band_cap=band_cap)
-    return wf.WFAResult(score, None, None, None, jnp.int32(s_max),
-                        m_bt, i_bt, d_bt)
+        ext_stride=ext_stride, band_cap=band_cap, counts=True)
+    return wf.WFAResult(score, None, None, None, steps, m_bt, i_bt, d_bt,
+                        n_ext_trips=trips)
 
 
 def _kernel_meet(pattern, text, plen, tlen, starget, *, pen, s_max, k_max,
@@ -302,11 +302,11 @@ def _kernel_backend(pattern, text, plen, tlen, *, pen, s_max, k_max,
                     heur=None, block_pairs=None, gather=None, ext_stride=1,
                     band_cap=None):
     from repro.kernels.wfa import ops as kops  # lazy: pallas import is heavy
-    score = kops.wfa_align(pattern, text, plen, tlen, pen=pen,
-                           s_max=s_max, k_max=k_max, heur=heur,
-                           block_pairs=block_pairs, gather=gather,
-                           ext_stride=ext_stride, band_cap=band_cap)
-    return wf.WFAResult(score, None, None, None, jnp.int32(s_max))
+    score, steps, trips = kops.wfa_align(
+        pattern, text, plen, tlen, pen=pen, s_max=s_max, k_max=k_max,
+        heur=heur, block_pairs=block_pairs, gather=gather,
+        ext_stride=ext_stride, band_cap=band_cap, counts=True)
+    return wf.WFAResult(score, None, None, None, steps, n_ext_trips=trips)
 
 
 def _shardmap_trace(pattern, text, plen, tlen, *, pen, s_max, k_max, mesh,

@@ -69,6 +69,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -85,6 +86,10 @@ from repro.core.backends import BackendSpec, get_backend, _accepts_kw
 from repro.core.penalties import DEFAULT
 
 Seq = Union[str, bytes, np.ndarray]
+
+# Registry counter of host seconds spent in executables' first calls,
+# where JAX traces and compiles (or reads its persistent cache).
+COMPILE_SECONDS = "engine_compile_seconds_total"
 
 
 # ---------------------------------------------------------------------------
@@ -237,6 +242,8 @@ class EngineStats:
     cache_hits: int = 0
     cache_misses: int = 0
     n_traces: int = 0          # fresh XLA traces triggered by this call
+    n_ext_trips: int = 0       # Pallas-kernel extend trips, summed over
+                               # grid blocks (0 on backends without them)
     rows_real: int = 0         # submitted rows actually dispatched in waves
     rows_padded: int = 0       # device rows incl. quantization padding
     bytes_in: int = 0
@@ -264,9 +271,9 @@ class EngineStats:
         self.n_workers = max(self.n_workers, other.n_workers)
         self.buckets.extend(other.buckets)
         for f in ("n_overflow", "n_recovered", "cache_hits", "cache_misses",
-                  "n_traces", "rows_real", "rows_padded", "bytes_in",
-                  "bytes_out", "t_scatter", "t_kernel", "t_gather",
-                  "n_meet_unmet", "n_bidir_fallback"):
+                  "n_traces", "n_ext_trips", "rows_real", "rows_padded",
+                  "bytes_in", "bytes_out", "t_scatter", "t_kernel",
+                  "t_gather", "n_meet_unmet", "n_bidir_fallback"):
             setattr(self, f, getattr(self, f) + getattr(other, f))
         self.peak_trace_bytes = max(self.peak_trace_bytes,
                                     other.peak_trace_bytes)
@@ -350,6 +357,7 @@ class _Executable:
                  opts: Tuple[Tuple[str, object], ...] = ()):
         self.s_max = s_max
         self.k_max = k_max
+        self.compiled = False     # set by the first call
         self._traces = [0]
         traces = self._traces
         pen = scoring.as_model(pen)
@@ -412,6 +420,20 @@ class _Executable:
         self.fn = jax.jit(_run, donate_argnums=donate)
 
     def call(self, *arrays):
+        if self.compiled:
+            return self._call(arrays)
+        # the first call traces and compiles (or reads JAX's persistent
+        # cache) before it dispatches: its host seconds are set-up
+        t0 = time.perf_counter()
+        res = self._call(arrays)
+        self.compiled = True
+        obs_metrics.counter(COMPILE_SECONDS,
+                            "host seconds in executables' first calls "
+                            "(trace + compile or persistent-cache read)"
+                            ).inc(time.perf_counter() - t0)
+        return res
+
+    def _call(self, arrays):
         if self._dispatch is not None:
             return self._dispatch(self.fn, *arrays)
         return self.fn(*arrays)

@@ -57,15 +57,26 @@ import jax
 import numpy as np
 
 from repro.core import cigar as cigar_mod
-from repro.core.engine import (AlignmentEngine, BucketInfo, EngineResult,
-                               EngineStats, Seq, _fit_width, _pad_rows,
-                               _quantize_rows, _round_up, pack_batch)
+from repro.core.engine import (COMPILE_SECONDS, AlignmentEngine, BucketInfo,
+                               EngineResult, EngineStats, Seq, _fit_width,
+                               _pad_rows, _quantize_rows, _round_up,
+                               pack_batch)
 from repro.obs import metrics as obs_metrics
-from repro.obs import profile as obs_profile
 from repro.obs import record as obs_record
 from repro.obs import trace as obs_trace
 
-__all__ = ["AlignmentSession", "SessionStats", "Ticket", "run_streamed"]
+__all__ = ["AlignmentSession", "SessionStats", "Ticket", "WAVE_COUNTERS",
+           "WAVE_SPANS", "run_streamed"]
+
+# The spans each wave opens (pack, then dispatch with a compile inside it
+# on a cache miss; wait, gather and, for CIGARs, traceback at retirement)
+# and the registry counters the wave path adds to.  Readers outside the
+# package look these names up here, so a rename shows as a missing name
+# rather than as an empty reading.
+WAVE_SPANS = ("wave.pack", "wave.dispatch", "wave.compile", "wave.wait",
+              "wave.gather", "wave.traceback")
+WAVE_COUNTERS = ("kernel_pairs_total", "kernel_score_steps_total",
+                 "kernel_extend_trips_total", COMPILE_SECONDS)
 
 
 @dataclasses.dataclass
@@ -383,15 +394,13 @@ class AlignmentSession:
         while len(self._inflight) >= self.max_inflight:
             self._retire_one()
         eng = self.engine
-        with obs_trace.span(
-                "wave.scatter", cat="wave",
-                args={"ticket": ticket.index, "rows": len(rows),
-                      "width": width, "s_max": s_max,
-                      "recovery": recovery}
-                if obs_trace.enabled() else None) as sp:
+        args = ({"ticket": ticket.index, "rows": len(rows), "width": width,
+                 "s_max": s_max, "recovery": recovery}
+                if obs_trace.recording() else None)
+        t0 = time.perf_counter()
+        with obs_trace.span("wave.pack", cat="wave", args=args) as sp:
             for fid in ticket.flows:
                 sp.flow_step(fid)
-            t0 = time.perf_counter()
             # quantized for cache reuse, but never above the per-wave
             # memory cap
             nb = min(_quantize_rows(len(rows), eng.n_workers),
@@ -419,39 +428,49 @@ class AlignmentSession:
             for st in (ticket.stats, self.stats):
                 st.rows_real += len(rows)
                 st.rows_padded += nb
-            pre = exe.n_traces
-            try:
-                with obs_profile.annotation("wfa.kernel.dispatch"):
-                    dev = eng._device_put(*arrays)
-                    if self._sync:
-                        jax.block_until_ready(dev)
-                        t1 = time.perf_counter()
-                        for st in (ticket.stats, self.stats):
-                            st.t_scatter += t1 - t0
-                    res = exe.call(*dev)
+        pre = exe.n_traces
+        try:
+            with obs_trace.span("wave.dispatch", cat="wave",
+                                args=args) as sp:
+                for fid in ticket.flows:
+                    sp.flow_step(fid)
+                dev = eng._device_put(*arrays)
                 if self._sync:
-                    res.score.block_until_ready()
-                    t2 = time.perf_counter()
-                    for st in (ticket.stats, self.stats):
-                        st.t_kernel += t2 - t1
-                else:
-                    # async: pack + enqueue cost only; the copy and kernel
-                    # are both still in flight behind this wave
+                    jax.block_until_ready(dev)
                     t1 = time.perf_counter()
                     for st in (ticket.stats, self.stats):
                         st.t_scatter += t1 - t0
-            except Exception as e:
-                self._error = e
-                self._abandon_inflight()
-                raise
-            n_tr = exe.n_traces - pre
-            for st in (ticket.stats, self.stats):
-                st.n_traces += n_tr
-            keep = ticket.output == "cigar"
-            self._inflight.append(_Wave(ticket, rows, res, plc, tlc, k_max,
-                                        recovery,
-                                        pc=pc if keep else None,
-                                        tc=tc if keep else None))
+                if hit:
+                    res = exe.call(*dev)
+                else:
+                    # the fresh executable traces and compiles (or reads
+                    # JAX's persistent cache) inside this call
+                    with obs_trace.span("wave.compile", cat="wave",
+                                        args=args):
+                        res = exe.call(*dev)
+            if self._sync:
+                with obs_trace.span("wave.wait", cat="wave", args=args):
+                    res.score.block_until_ready()
+                t2 = time.perf_counter()
+                for st in (ticket.stats, self.stats):
+                    st.t_kernel += t2 - t1
+            else:
+                # async: pack + enqueue cost only; the copy and kernel
+                # are both still in flight behind this wave
+                t1 = time.perf_counter()
+                for st in (ticket.stats, self.stats):
+                    st.t_scatter += t1 - t0
+        except Exception as e:
+            self._error = e
+            self._abandon_inflight()
+            raise
+        n_tr = exe.n_traces - pre
+        for st in (ticket.stats, self.stats):
+            st.n_traces += n_tr
+        keep = ticket.output == "cigar"
+        self._inflight.append(_Wave(ticket, rows, res, plc, tlc, k_max,
+                                    recovery, pc=pc if keep else None,
+                                    tc=tc if keep else None))
         self.stats.n_waves += 1
         self.stats.peak_inflight = max(self.stats.peak_inflight,
                                        len(self._inflight))
@@ -473,16 +492,15 @@ class AlignmentSession:
         wave = self._inflight.popleft()
         ticket = wave.ticket
         self._sample_inflight()
-        _on = obs_trace.enabled()
         _args = ({"ticket": ticket.index, "rows": len(wave.rows),
-                  "recovery": wave.recovery} if _on else None)
+                  "recovery": wave.recovery}
+                 if obs_trace.recording() else None)
         t0 = time.perf_counter()
-        with obs_trace.span("wave.kernel", cat="wave", args=_args) as sp:
+        with obs_trace.span("wave.wait", cat="wave", args=_args) as sp:
             for fid in ticket.flows:
                 sp.flow_step(fid)
             try:
-                with obs_profile.annotation("wfa.kernel.wait"):
-                    wave.res.score.block_until_ready()
+                wave.res.score.block_until_ready()
             except Exception as e:
                 self._error = e
                 self._abandon_inflight()
@@ -492,9 +510,14 @@ class AlignmentSession:
         sp.__enter__()
         for fid in ticket.flows:
             sp.flow_step(fid)
-        full = np.asarray(wave.res.score)
+        # one batch of device-to-host copies, so the counters' reads
+        # overlap the scores' (breakpoint waves carry no trip counter)
+        full, steps, trips = jax.device_get(
+            (wave.res.score, wave.res.n_steps,
+             getattr(wave.res, "n_ext_trips", None)))
         out = full[: len(wave.rows)]
-        steps = int(wave.res.n_steps)
+        steps = int(steps)
+        trips = None if trips is None else int(trips)
         t2 = time.perf_counter()
         if not self._sync:       # sync mode billed the kernel at dispatch
             for st in (ticket.stats, self.stats):
@@ -504,6 +527,18 @@ class AlignmentSession:
             st.bytes_out += full.nbytes
         ticket._scores[wave.rows] = out
         ticket._steps += steps
+        obs_metrics.counter("kernel_pairs_total",
+                            "real pair rows retired from waves"
+                            ).inc(len(wave.rows))
+        obs_metrics.counter("kernel_score_steps_total",
+                            "score-loop steps of retired waves"
+                            ).inc(steps)
+        if trips is not None:
+            for st in (ticket.stats, self.stats):
+                st.n_ext_trips += trips
+            obs_metrics.counter("kernel_extend_trips_total",
+                                "extend trips of retired waves, summed "
+                                "over the kernel's grid blocks").inc(trips)
         if ticket._meet is not None:
             r = wave.res
             nr = len(wave.rows)

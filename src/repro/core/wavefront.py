@@ -127,10 +127,15 @@ class WFAResult(NamedTuple):
     m_hist: Optional[jax.Array]  # [s_max+1, B, K] or None
     i_hist: Optional[jax.Array]  # None for linear models (no I/D fronts)
     d_hist: Optional[jax.Array]
-    n_steps: jax.Array          # [] int32: score loop trips taken (telemetry)
+    # [] int32: score loop trips taken (telemetry); the Pallas kernel's
+    # are summed over its grid blocks, each running its own loop
+    n_steps: jax.Array
     m_bt: Optional[jax.Array] = None  # [n_trace_words, B, K] packed 2-bit
     i_bt: Optional[jax.Array] = None  # provenance codes, or None (score mode
     d_bt: Optional[jax.Array] = None  # / linear models)
+    # [] int32 extend trips summed over the Pallas kernel's grid blocks
+    # (telemetry); None for backends without a trip counter
+    n_ext_trips: Optional[jax.Array] = None
 
 
 def _shift_from_km1(w):

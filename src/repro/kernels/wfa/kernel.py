@@ -132,7 +132,8 @@ def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
     C = ext_stride if gather == "index" else 1
     kc_full = k_pad // 2                     # absolute diagonal center
 
-    def kernel(p_ref, t_ref, pl_ref, tl_ref, out_ref, steps_ref, *refs):
+    def kernel(p_ref, t_ref, pl_ref, tl_ref, out_ref, steps_ref, trips_ref,
+               *refs):
         bt_refs = refs[:n_bt]
         rings = refs[:-1][n_bt:] if band else refs[n_bt:]
         off_ref = refs[-1] if band else None  # [W, 1] SMEM row offsets
@@ -155,8 +156,11 @@ def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
             return jidx + (off - kc_full)
 
         def extend(M, ks):
+            """-> (extended M, extend trips taken: one per lock-step
+            fetch over the block's [BP, Kc] front)."""
             def trip(st):
-                M, _ = st
+                M, _, n = st
+                n = n + 1
                 v = M - ks
                 base = (M > _THRESH)
                 if C == 1:
@@ -165,7 +169,7 @@ def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
                     tc = _gather_chars(txt, M, gather)
                     pc = _gather_chars(pat, v, gather)
                     adv = (can & (tc == pc)).astype(jnp.int32)
-                    return M + adv, jnp.any(adv == 1)
+                    return M + adv, jnp.any(adv == 1), n
                 tcs = _gather_strided(txt, M, C)
                 pcs = _gather_strided(pat, v, C)
                 cidx = lax.broadcasted_iota(jnp.int32, (BP, Kc, C), 2)
@@ -178,11 +182,11 @@ def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
                 # matched prefix length along the stride
                 adv = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=2),
                               axis=2)
-                return M + adv, jnp.any(adv >= C)
+                return M + adv, jnp.any(adv >= C), n
 
-            st = trip((M, jnp.bool_(True)))
-            M, _ = lax.while_loop(lambda st: st[1], trip, st)
-            return M
+            st = trip((M, jnp.bool_(True), jnp.int32(0)))
+            M, _, n = lax.while_loop(lambda st: st[1], trip, st)
+            return M, n
 
         def reached(M, ks):
             """[BP, 1] bool: furthest offset hit the (tlen, plen) corner."""
@@ -247,7 +251,7 @@ def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
         else:
             off0 = 0
         ks0 = ks_of(off0)
-        M0 = extend(jnp.where(ks0 == 0, 0, NEG), ks0)
+        M0, trips0 = extend(jnp.where(ks0 == 0, 0, NEG), ks0)
         store_row(m_ring, 0, M0)
         if affine:
             store_row(i_ring, 0, neg_kc)
@@ -275,7 +279,7 @@ def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
             return jnp.where(hi >= lo, new, poff)
 
         def body(carry):
-            s, score = carry
+            s, score, trips = carry
             off = recenter(s)
             ks = ks_of(off)
             m_x = load_row(m_ring, s, x, off)
@@ -299,7 +303,7 @@ def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
             X_new = jnp.where((m_x > _THRESH) & (m_x + 1 <= tlen)
                               & (m_x + 1 - ks <= plen), m_x + 1, NEG)
             M_pre = jnp.maximum(jnp.maximum(X_new, I_new), D_new)
-            M_new = extend(M_pre, ks)
+            M_new, n_trips = extend(M_pre, ks)
 
             if trace:
                 # codes from the PRE-prune fronts — bit-identical to
@@ -336,15 +340,19 @@ def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
                 store_row(d_ring, row, D_new)
             if band:
                 off_ref[row, 0] = off
-            return s + 1, score
+            return s + 1, score, trips + n_trips
 
         def cond(carry):
-            s, score = carry
+            s, score, _ = carry
             return (s <= s_max) & jnp.any(score < 0)
 
-        s_end, score = lax.while_loop(cond, body, (jnp.int32(1), score0))
+        s_end, score, trips = lax.while_loop(
+            cond, body, (jnp.int32(1), score0, trips0))
         out_ref[...] = score
+        # the block's loop counters, broadcast over its rows: score steps
+        # (rows 0 .. s_end - 1 computed) and extend trips
         steps_ref[...] = jnp.broadcast_to(s_end, steps_ref.shape)
+        trips_ref[...] = jnp.broadcast_to(trips, trips_ref.shape)
 
     return kernel, W, affine
 
@@ -359,7 +367,9 @@ def wfa_pallas(pattern, text, plen, tlen, *, pen, s_max: int,
                ext_stride: int = 1, band_cap=None):
     """pattern/text [B, L*] int32 (B % block_pairs == 0, L* % 128 == 0),
     plen/tlen [B, 1] int32, k_pad % 128 == 0 is the padded diagonal count.
-    -> (score [B, 1] int32, steps [B, 1] int32); with ``trace`` additionally
+    -> (score [B, 1] int32, steps [B, 1] int32, trips [B, 1] int32), the
+    last two the block's score steps and extend trips on each of its rows;
+    with ``trace`` additionally
     the [n_words, B, k_pad] int32 packed provenance arrays (three for
     affine models, one for linear).
 
@@ -394,9 +404,8 @@ def wfa_pallas(pattern, text, plen, tlen, *, pen, s_max: int,
     n_rings = 3 if affine else 1
 
     spec2 = lambda L: pl.BlockSpec((BP, L), lambda i: (i, 0))
-    out_specs = [spec2(1), spec2(1)]
-    out_shape = [jax.ShapeDtypeStruct((B, 1), jnp.int32),
-                 jax.ShapeDtypeStruct((B, 1), jnp.int32)]
+    out_specs = [spec2(1)] * 3
+    out_shape = [jax.ShapeDtypeStruct((B, 1), jnp.int32)] * 3
     if trace:
         NW = n_trace_words(s_max)
         bt_spec = pl.BlockSpec((NW, BP, k_pad), lambda i: (0, i, 0))

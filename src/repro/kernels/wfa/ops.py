@@ -87,15 +87,23 @@ def _prep(pattern, text, plen, tlen, block_pairs):
     return pattern, text, plen2, tlen2, B
 
 
+def _block_sums(steps, trips, bp: int):
+    """The kernel's per-block loop counters ([B, 1], one value repeated
+    over each block's rows) summed over grid blocks -> two [] int32."""
+    return steps[::bp, 0].sum(), trips[::bp, 0].sum()
+
+
 def wfa_align(pattern, text, plen, tlen, *, pen, s_max: int,
               k_max: int, block_pairs: Optional[int] = None,
               interpret: Optional[bool] = None, heur=None,
               gather: Optional[str] = None, ext_stride: int = 1,
-              band_cap: Optional[int] = None):
+              band_cap: Optional[int] = None, counts: bool = False):
     """Batched WFA scores via the Pallas kernel.
 
     pattern/text: [B, L*] int; plen/tlen: [B] int.  Returns [B] int32 costs
-    (-1 where the optimal cost exceeds ``s_max``).  ``pen`` may be any
+    (-1 where the optimal cost exceeds ``s_max``); with ``counts``,
+    ``(score, steps, trips)``: the score steps and extend trips of every
+    grid block, summed.  ``pen`` may be any
     ``PenaltyModel`` (or a legacy ``Penalties`` triple) and ``heur`` an
     optional ``WavefrontHeuristic``; both specialize the kernel statically.
     ``interpret`` defaults to True off-TPU (CPU validation) and False on
@@ -107,11 +115,13 @@ def wfa_align(pattern, text, plen, tlen, *, pen, s_max: int,
     pattern, text, plen2, tlen2, B = _prep(pattern, text, plen, tlen, bp)
     k_pad = _round_up(2 * k_max + 1, LANE)
 
-    score, _ = wfa_pallas(pattern, text, plen2, tlen2, pen=pen, s_max=s_max,
-                          k_pad=k_pad, block_pairs=bp, interpret=interpret,
-                          heur=scoring.as_heuristic(heur), gather=gather,
-                          ext_stride=ext_stride,
-                          band_cap=_band_lanes(band_cap, k_pad))
+    score, steps, trips = wfa_pallas(
+        pattern, text, plen2, tlen2, pen=pen, s_max=s_max, k_pad=k_pad,
+        block_pairs=bp, interpret=interpret,
+        heur=scoring.as_heuristic(heur), gather=gather,
+        ext_stride=ext_stride, band_cap=_band_lanes(band_cap, k_pad))
+    if counts:
+        return (score[:B, 0],) + _block_sums(steps, trips, bp)
     return score[:B, 0]
 
 
@@ -119,7 +129,7 @@ def wfa_align_trace(pattern, text, plen, tlen, *, pen, s_max: int,
                     k_max: int, block_pairs: Optional[int] = None,
                     interpret: Optional[bool] = None, heur=None,
                     gather: Optional[str] = None, ext_stride: int = 1,
-                    band_cap: Optional[int] = None):
+                    band_cap: Optional[int] = None, counts: bool = False):
     """Batched WFA scores *plus* packed backtrace via the Pallas kernel.
 
     Same padding contract as :func:`wfa_align`; returns
@@ -129,6 +139,8 @@ def wfa_align_trace(pattern, text, plen, tlen, *, pen, s_max: int,
     center is ``k_pad // 2`` — under a compacting band the codes are
     scattered back to absolute k in-kernel, so the decoder is unchanged).
     Linear penalty models record a single M plane: ``i_bt = d_bt = None``.
+    ``counts`` appends the summed score steps and extend trips, as in
+    :func:`wfa_align`.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -141,11 +153,12 @@ def wfa_align_trace(pattern, text, plen, tlen, *, pen, s_max: int,
         block_pairs=bp, interpret=interpret, trace=True,
         heur=scoring.as_heuristic(heur), gather=gather,
         ext_stride=ext_stride, band_cap=_band_lanes(band_cap, k_pad))
-    if scoring.as_model(pen).kind == "linear":
-        score, _, m_bt = out
-        return score[:B, 0], m_bt[:, :B, :], None, None
-    score, _, m_bt, i_bt, d_bt = out
-    return (score[:B, 0], m_bt[:, :B, :], i_bt[:, :B, :], d_bt[:, :B, :])
+    score, steps, trips, m_bt, *gaps = out
+    i_bt, d_bt = (g[:, :B, :] for g in gaps) if gaps else (None, None)
+    res = (score[:B, 0], m_bt[:, :B, :], i_bt, d_bt)
+    if counts:
+        return res + _block_sums(steps, trips, bp)
+    return res
 
 
 def wfa_align_np(pattern, text, plen, tlen, **kw):

@@ -6,7 +6,8 @@ writes, plus the analyses the paper's evaluation is built on:
 
 * **Phase accounting** — per-wave span time grouped into the paper's
   Fig. 1 split.  Our spans map onto it as
-  ``wave.scatter`` → CPU→DPU *transfer*, ``wave.kernel`` → *kernel*,
+  ``wave.pack``/``wave.dispatch`` → CPU→DPU *transfer*, ``wave.wait``
+  (the host blocked on the device) → *kernel*,
   ``wave.gather``/``wave.traceback`` → DPU→CPU *retrieve* (+ host
   post-processing).  :func:`phase_accounting` reproduces that
   breakdown from any capture.
@@ -45,8 +46,9 @@ __all__ = ["Bubble", "CounterPoint", "FlowPath", "InstantPoint",
 # accounting unit; everything else (session.submit, serve.*) shows up
 # in critical paths but not the phase table.
 SPAN_PHASE: Dict[str, str] = {
-    "wave.scatter": "scatter",
-    "wave.kernel": "kernel",
+    "wave.pack": "scatter",
+    "wave.dispatch": "scatter",
+    "wave.wait": "kernel",
     "wave.gather": "gather",
     "wave.traceback": "traceback",
 }
@@ -281,7 +283,7 @@ def phase_accounting(trace: Trace,
 
 
 def slow_waves(trace: Trace, k: int = 8,
-               name: str = "wave.kernel") -> List[SpanEvent]:
+               name: str = "wave.wait") -> List[SpanEvent]:
     """The ``k`` longest spans of one wave phase, worst first."""
     return sorted(trace.spans_named(name),
                   key=lambda s: s.dur, reverse=True)[:max(0, k)]
@@ -424,7 +426,7 @@ def pipeline_analysis(trace: Trace,
     The counter samples form a step function; intervals where it is
     positive are device-busy, zero-valued gaps between them are
     pipeline **bubbles** (the host failed to keep a wave in flight).
-    Falls back to the union of ``wave.kernel`` spans when the counter
+    Falls back to the union of ``wave.wait`` spans when the counter
     track is absent (e.g. a flight-recorder ring that rolled past it).
     """
     samples = [c for c in trace.counters if c.name == counter]
@@ -454,7 +456,7 @@ def pipeline_analysis(trace: Trace,
         mean_inflight = area / total_dt if total_dt > 0 else 0.0
     else:
         busy = _union([(s.ts, s.end) for s in trace.spans_named(
-            "wave.kernel")])
+            "wave.wait")])
         if busy:
             span = busy[-1][1] - busy[0][0]
             busy_total = sum(hi - lo for lo, hi in busy)
@@ -467,8 +469,8 @@ def pipeline_analysis(trace: Trace,
     span_us = busy[-1][1] - busy[0][0] if busy else 0.0
     busy_us = sum(hi - lo for lo, hi in busy)
     host = _union([(s.ts, s.end) for s in trace.spans
-                   if s.name in ("wave.scatter", "wave.gather",
-                                 "wave.traceback")])
+                   if s.name in ("wave.pack", "wave.dispatch",
+                                 "wave.gather", "wave.traceback")])
     host_busy_us = sum(hi - lo for lo, hi in host)
     host_overlap_us = _intersect_len(host, busy)
     return PipelineReport(span_us=span_us, busy_us=busy_us, bubbles=bubbles,

@@ -1,14 +1,16 @@
 """`jax.profiler` bridge: device-level traces lined up with our spans.
 
-:func:`profile` wraps a block in ``jax.profiler.trace(outdir)`` (the
-``--profile DIR`` flag on the launchers), capturing XLA/TPU activity
-viewable in TensorBoard or Perfetto.  :func:`annotation` emits a named
-``jax.profiler.TraceAnnotation`` **only while a profile is active**, so
-the instrumented hot path (session dispatch, kernel wait) carries the
-same stage names in the device trace as in :mod:`repro.obs.trace`'s
-host-side timeline — matching up "wave.kernel" on both sides is how the
-paper's scatter/kernel/gather phase split (Fig. 1) is attributed to real
-device time.
+:func:`profile` wraps a block in a ``jax.profiler`` capture into
+``outdir`` (the ``--profile DIR`` flag on the launchers), with the python
+tracer off, capturing XLA/TPU activity viewable in TensorBoard or
+Perfetto.  While a capture is active (:data:`_active`), every
+:func:`repro.obs.trace.span` also opens a ``jax.profiler.TraceAnnotation``
+of the same name through :func:`annotation`, so the session's wave spans
+(``wave.pack``, ``wave.dispatch``, ``wave.compile``, ``wave.wait``,
+``wave.gather``, ``wave.traceback``) sit on the device trace's clock with
+their ``ticket``/``rows`` args as event stats.  A harness that starts
+``jax.profiler`` itself sets :data:`_active` over its window to get the
+same annotations.
 
 When no profile is active, :func:`annotation` returns a shared no-op
 context manager (no allocation), mirroring the disabled-mode contract of
@@ -21,8 +23,8 @@ from typing import Iterator, Optional
 
 __all__ = ["active", "annotation", "profile"]
 
-# Set only while a profile() block is running; annotation() gates on it so
-# steady-state code pays one branch when not profiling.
+# Set only while a profile is running; annotation() and obs.trace.span()
+# gate on it so steady-state code pays one branch when not profiling.
 _active = False
 
 _NULL_CTX = contextlib.nullcontext()
@@ -36,26 +38,31 @@ def active() -> bool:
 def profile(outdir: Optional[str]) -> Iterator[None]:
     """Capture a ``jax.profiler`` trace of the block into ``outdir``
     (``None`` → no-op, so callers can pass an optional CLI flag straight
-    through).  View with TensorBoard's profile plugin or Perfetto."""
+    through).  The python tracer is off, so the trace holds the device's
+    ops and the program's spans, as the benchmark's traced runs do.  View
+    with TensorBoard's profile plugin or Perfetto."""
     global _active
     if not outdir:
         yield
         return
     import jax
 
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
     _active = True
     try:
-        with jax.profiler.trace(outdir):
+        with jax.profiler.trace(outdir, profiler_options=opts):
             yield
     finally:
         _active = False
 
 
-def annotation(name: str):
-    """A named ``TraceAnnotation`` scope when a profile is active, else a
-    shared no-op context manager."""
+def annotation(name: str, **args):
+    """A named ``TraceAnnotation`` scope (``args`` become its event
+    stats) when a profile is active, else a shared no-op context
+    manager."""
     if not _active:
         return _NULL_CTX
     import jax
 
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, **args)

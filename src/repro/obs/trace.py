@@ -16,6 +16,12 @@ Design constraints (this layer stays compiled into the hot path):
 * **thread-safe** — events are appended under one lock; timestamps come
   from a single ``time.perf_counter`` origin so spans from any number of
   threads land on one consistent timeline (per-thread lanes via ``tid``).
+* **one vocabulary on two clocks** — while a ``jax.profiler`` capture
+  is active (:data:`repro.obs.profile._active`), every span also opens a
+  ``jax.profiler.TraceAnnotation`` of the same name with its args as
+  event stats, so the program's spans sit on the device trace's clock
+  and name its idle gaps.  With tracer, flight recorder and profile all
+  off, :func:`span` still returns :data:`NULL` after three global reads.
 * **flow IDs** — :func:`new_flow` allocates process-unique IDs;
   ``Span.flow_start/flow_step/flow_end`` emit flow events *inside* the
   span (same thread + a timestamp within the slice), which is how
@@ -27,7 +33,7 @@ Usage::
     from repro.obs import trace
 
     trace.enable()
-    with trace.span("wave.kernel", args={"rows": 256}) as sp:
+    with trace.span("wave.wait", args={"rows": 256}) as sp:
         sp.flow_step(fid)          # arrow through this span
         ...
     trace.counter("inflight", 2)
@@ -43,9 +49,11 @@ import threading
 import time
 from typing import Dict, Iterator, List, Optional
 
+from repro.obs import profile as _profile
+
 __all__ = ["NULL", "Span", "counter", "disable", "enable", "enabled",
-           "events", "instant", "isolated", "new_flow", "reset", "save",
-           "span"]
+           "events", "instant", "isolated", "new_flow", "recording",
+           "reset", "save", "span"]
 
 _PID = os.getpid()
 _T0 = time.perf_counter()
@@ -110,6 +118,12 @@ def enabled() -> bool:
     return _on
 
 
+def recording() -> bool:
+    """True when span args are read: the tracer is on or a profiler
+    capture is active (the args become the annotation's event stats)."""
+    return _on or _profile._active
+
+
 def reset() -> None:
     """Drop every buffered event (the switch state is unchanged)."""
     with _lock:
@@ -152,10 +166,12 @@ class Span:
 
     Created via :func:`span` (never directly) — when tracing is off that
     returns the shared no-op :data:`NULL` instead, so every method here
-    can assume the tracer is live.
+    can assume the tracer, the flight recorder or a profile is live.
+    While a profile is active the span also holds a profiler annotation
+    of its name, open from ``__enter__`` to ``__exit__``.
     """
 
-    __slots__ = ("name", "cat", "args", "_ts", "_tid")
+    __slots__ = ("name", "cat", "args", "_ts", "_tid", "_ann")
 
     def __init__(self, name: str, cat: str, args: Optional[dict]):
         self.name = name
@@ -163,11 +179,20 @@ class Span:
         self.args = dict(args) if args else {}
         self._tid = threading.get_ident()
         self._ts = _now_us()
+        self._ann = None
 
     def __enter__(self) -> "Span":
+        if _profile._active:
+            self._ann = _profile.annotation(self.name, **self.args)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        if not _on and _rec is None:
+            return                # profile only: the annotation is the event
         _emit({"name": self.name, "cat": self.cat, "ph": "X",
                "ts": self._ts, "dur": _now_us() - self._ts,
                "pid": _PID, "tid": self._tid, "args": self.args})
@@ -231,11 +256,12 @@ def span(name: str, cat: str = "repro",
          args: Optional[dict] = None) -> "Span | _NullSpan":
     """Open a span (use as a context manager).  Disabled → :data:`NULL`.
 
-    When the flight recorder is active the real span is created even
-    with the tracer off, so the ring sees recent history; the fully-off
-    path (no tracer, no recorder) still allocates nothing.
+    When the flight recorder or a profile capture is active the real span
+    is created even with the tracer off, so the ring sees recent history
+    and the profiler gets the annotation; the fully-off path (no tracer,
+    no recorder, no profile) still allocates nothing.
     """
-    if not _on and _rec is None:
+    if not _on and _rec is None and not _profile._active:
         return NULL
     return Span(name, cat, args)
 

@@ -2,17 +2,22 @@
 linking submit -> retire across out-of-order tickets, bounded-memory
 histogram accuracy, Prometheus exposition, ServerStats/scrape percentile
 parity, EngineStats.merge, and the overhead-gate CI wiring."""
+import glob
 import json
+import os
 import threading
 
+import jax
 import numpy as np
 import pytest
 from conftest import random_pairs as _random_pairs
 
 from repro import obs
 from repro.core.engine import AlignmentEngine, BucketInfo, EngineStats
+from repro.core.session import WAVE_SPANS
 from repro.data.reads import ArrivalSpec, generate_trace
 from repro.obs import metrics as obs_metrics
+from repro.obs import profile as obs_profile
 from repro.obs import trace as obs_trace
 from repro.serve import ServeLoop, replay_trace
 
@@ -35,6 +40,9 @@ def tracer():
 def test_disabled_mode_emits_nothing_and_allocates_nothing():
     obs_trace.disable()
     obs_trace.reset()
+    # tracer, flight recorder and profile all off
+    assert not obs_profile.active() and obs_trace._rec is None
+    assert not obs_trace.recording()
     # the disabled span is THE shared singleton: no per-call allocation
     assert obs_trace.span("x") is obs_trace.NULL
     assert obs_trace.span("y", cat="c", args={"k": 1}) is obs_trace.NULL
@@ -44,6 +52,17 @@ def test_disabled_mode_emits_nothing_and_allocates_nothing():
         sp.flow_end(7)
     obs_trace.instant("i", args={"k": 2})
     obs_trace.counter("c", 3)
+    assert obs_trace.events() == []
+    # the profile flag alone makes spans real (each carries a profiler
+    # annotation), and still nothing reaches the tracer's buffer
+    obs_profile._active = True
+    try:
+        assert obs_trace.recording()
+        with obs_trace.span("p", args={"k": 3}) as sp:
+            assert sp is not obs_trace.NULL
+    finally:
+        obs_profile._active = False
+    assert obs_trace.span("x") is obs_trace.NULL
     assert obs_trace.events() == []
 
 
@@ -97,8 +116,8 @@ def test_flow_ids_connect_submit_to_retire_out_of_order(tracer, rng):
             tk.result()
     ev = tracer.events()
     names = {e["name"] for e in ev if e["ph"] == "X"}
-    for expected in ("session.submit", "wave.scatter", "wave.kernel",
-                     "wave.gather", "session.ticket_done"):
+    for expected in ("session.submit", "wave.pack", "wave.dispatch",
+                     "wave.wait", "wave.gather", "session.ticket_done"):
         assert expected in names, f"missing span {expected}"
     flows = {}
     for e in ev:
@@ -112,6 +131,99 @@ def test_flow_ids_connect_submit_to_retire_out_of_order(tracer, rng):
         assert "t" in phs                         # >= 1 wave step between
         ts = [e["ts"] for e in chain]
         assert ts[0] <= min(ts) and ts[-1] >= max(ts) - 1e-6
+
+
+# ------------------------------------------------- profiler annotations ----
+
+
+def _profiled_wave_spans(outdir):
+    """[(name, start_ns, end_ns, stats)] of the ``wave.*`` events on the
+    host plane of the profile written under ``outdir``, read with
+    ``ProfileData`` as the benchmark's trace reduction reads them, and
+    whether any python-tracer event (``$``-named) is there."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(outdir, "**", "*.xplane.pb"),
+                      recursive=True)
+    spans, python_events = [], False
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for ln in plane.lines:
+            for ev in ln.events:
+                python_events |= ev.name.startswith("$")
+                if ev.name.startswith("wave."):
+                    spans.append((ev.name, ev.start_ns,
+                                  ev.start_ns + ev.duration_ns,
+                                  dict(ev.stats)))
+    return sorted(spans, key=lambda sp: sp[1]), python_events
+
+
+def _one_wave_tickets(eng, chunks):
+    with eng.stream(max_inflight_waves=2) as sess:
+        for p, t in chunks:
+            sess.submit(p, t)
+        for tk in sess.as_completed():
+            tk.result()
+    return sess.stats
+
+
+def test_session_spans_land_in_the_profiler_trace(tmp_path, rng):
+    """With the tracer off and ``obs.profile._active`` set over a
+    ``jax.profiler`` capture (as the benchmark's traced window does), each
+    wave puts one pack, dispatch, wait and gather span into the host
+    plane, pack ending before dispatch starts, each carrying its ticket;
+    a ``wave.compile`` sits inside the dispatch of each cache miss only."""
+    obs_trace.disable()
+    eng = AlignmentEngine(backend="ring", edit_frac=0.1, chunk_pairs=8)
+    chunks = [_random_pairs(rng, 8, lo=40, hi=60) for _ in range(3)]
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    prev, obs_profile._active = obs_profile._active, True
+    try:
+        stats = _one_wave_tickets(eng, chunks)
+    finally:
+        obs_profile._active = prev
+        jax.profiler.stop_trace()
+    spans, _ = _profiled_wave_spans(str(tmp_path))
+    assert {n for n, *_ in spans} <= set(WAVE_SPANS)
+    count = {n: sum(1 for m, *_ in spans if m == n) for n in WAVE_SPANS}
+    assert stats.n_waves >= 3
+    for name in ("wave.pack", "wave.dispatch", "wave.wait", "wave.gather"):
+        assert count[name] == stats.n_waves, name
+    assert count["wave.traceback"] == 0              # scores only
+    assert all("ticket" in st and "rows" in st for *_, st in spans)
+    assert {st["ticket"] for *_, st in spans} == {0, 1, 2}
+    packs = [sp for sp in spans if sp[0] == "wave.pack"]
+    dispatches = [sp for sp in spans if sp[0] == "wave.dispatch"]
+    for pack, disp in zip(packs, dispatches):
+        assert pack[3]["ticket"] == disp[3]["ticket"]
+        assert pack[2] <= disp[1]                   # pack ends, then dispatch
+    compiles = [sp for sp in spans if sp[0] == "wave.compile"]
+    assert len(compiles) == stats.cache_misses >= 1
+    for c in compiles:
+        assert any(d[1] <= c[1] and c[2] <= d[2]
+                   and d[3]["ticket"] == c[3]["ticket"] for d in dispatches)
+
+
+def test_profile_block_has_spans_but_no_python_tracer(tmp_path, rng):
+    """``obs.profile.profile(dir)`` (the launchers' ``--profile DIR``)
+    starts the capture with the python tracer off and the spans on; a
+    warm engine's waves open no ``wave.compile``."""
+    obs_trace.disable()
+    eng = AlignmentEngine(backend="ring", edit_frac=0.1, chunk_pairs=8)
+    chunks = [_random_pairs(rng, 8, lo=40, hi=60) for _ in range(2)]
+    _one_wave_tickets(eng, chunks)                  # warm the cache
+    with obs_profile.profile(str(tmp_path)):
+        assert obs_profile.active()
+        stats = _one_wave_tickets(eng, chunks)
+    assert not obs_profile.active()
+    spans, python_events = _profiled_wave_spans(str(tmp_path))
+    assert not python_events
+    assert stats.cache_misses == 0
+    names = [n for n, *_ in spans]
+    assert "wave.compile" not in names
+    assert names.count("wave.wait") == stats.n_waves >= 2
 
 
 def test_capture_trace_writes_and_restores(tracer, tmp_path):

@@ -45,13 +45,15 @@ def _f(ph, fid, ts, tid):
 
 SYNTHETIC = [
     _x("session.submit", 0, 1_000, tid=2),
-    _x("wave.scatter", 0, 10_000, args={"wave": 0}),
-    _x("wave.kernel", 10_000, 20_000, args={"wave": 0, "rows": 256}),
+    _x("wave.pack", 0, 6_000, args={"wave": 0}),
+    _x("wave.dispatch", 6_000, 4_000, args={"wave": 0}),
+    _x("wave.wait", 10_000, 20_000, args={"wave": 0, "rows": 256}),
     _x("wave.gather", 30_000, 5_000, args={"wave": 0}),
     _x("wave.traceback", 35_000, 2_000, args={"wave": 0}),
     # deliberate bubble: nothing in flight 40_000 .. 60_500
-    _x("wave.scatter", 60_000, 4_000, args={"wave": 1}),
-    _x("wave.kernel", 64_000, 6_000, args={"wave": 1, "rows": 64}),
+    _x("wave.pack", 60_000, 2_500, args={"wave": 1}),
+    _x("wave.dispatch", 62_500, 1_500, args={"wave": 1}),
+    _x("wave.wait", 64_000, 6_000, args={"wave": 1, "rows": 64}),
     _x("wave.gather", 70_000, 1_000, args={"wave": 1}),
     _c("inflight_waves", 500, 1),
     _c("inflight_waves", 40_000, 0),
@@ -125,7 +127,7 @@ def test_cross_thread_critical_path(synth):
     p = paths[0]
     assert p.id == 7
     assert [s.name for s in p.segments] == \
-        ["session.submit", "wave.kernel", "wave.gather"]
+        ["session.submit", "wave.wait", "wave.gather"]
     assert {s.tid for s in p.segments} == {1, 2}    # crosses threads
     # kernel waited 9ms after submit ended (1_000 -> 10_000)
     assert p.segments[1].wait_us == pytest.approx(9_000)
