@@ -272,13 +272,13 @@ def _ring_backend(pattern, text, plen, tlen, *, pen, s_max, k_max, heur=None,
 
 
 def _kernel_trace(pattern, text, plen, tlen, *, pen, s_max, k_max,
-                  heur=None, block_pairs=None, gather=None, ext_stride=1,
+                  heur=None, block_pairs=None, gather=None, char_bits=32,
                   band_cap=None):
     from repro.kernels.wfa import ops as kops  # lazy: pallas import is heavy
     score, m_bt, i_bt, d_bt, steps, trips = kops.wfa_align_trace(
         pattern, text, plen, tlen, pen=pen, s_max=s_max, k_max=k_max,
         heur=heur, block_pairs=block_pairs, gather=gather,
-        ext_stride=ext_stride, band_cap=band_cap, counts=True)
+        char_bits=char_bits, band_cap=band_cap, counts=True)
     return wf.WFAResult(score, None, None, None, steps, m_bt, i_bt, d_bt,
                         n_ext_trips=trips)
 
@@ -299,13 +299,13 @@ def _kernel_meet(pattern, text, plen, tlen, starget, *, pen, s_max, k_max,
                   doc="Pallas TPU kernel (interpret on CPU); packed "
                       "backtrace in VMEM; fused in-grid BiWFA meet")
 def _kernel_backend(pattern, text, plen, tlen, *, pen, s_max, k_max,
-                    heur=None, block_pairs=None, gather=None, ext_stride=1,
+                    heur=None, block_pairs=None, gather=None, char_bits=32,
                     band_cap=None):
     from repro.kernels.wfa import ops as kops  # lazy: pallas import is heavy
     score, steps, trips = kops.wfa_align(
         pattern, text, plen, tlen, pen=pen, s_max=s_max, k_max=k_max,
         heur=heur, block_pairs=block_pairs, gather=gather,
-        ext_stride=ext_stride, band_cap=band_cap, counts=True)
+        char_bits=char_bits, band_cap=band_cap, counts=True)
     return wf.WFAResult(score, None, None, None, steps, n_ext_trips=trips)
 
 

@@ -117,6 +117,40 @@ def pack_batch(seqs: Sequence[Seq], pad_to: Optional[int] = None,
     return out, lens
 
 
+# ASCII codes that the kernel's 2-bit fetch packs one-to-one
+_ACGT = np.zeros(256, bool)
+_ACGT[np.frombuffer(b"ACGT", np.uint8)] = True
+
+
+def fetch_char_bits(p: np.ndarray, plen: np.ndarray, t: np.ndarray,
+                    tlen: np.ndarray) -> int:
+    """Bits per character of the kernel's packed extend fetch for a batch.
+
+    Only codes inside each row's length count: 2 when every one is an
+    uppercase A, C, G or T (16 characters compared per trip); else 8 when
+    every one lies in 0..255, as everything ``encode`` makes from ``str``
+    or ``bytes`` does (4 per trip); else 32 (1 per trip).
+    """
+    acgt = True
+    for codes, lens in ((p, plen), (t, tlen)):
+        codes = np.asarray(codes)
+        lens = np.asarray(lens).reshape(-1)
+        if codes.size == 0:
+            continue
+        if codes.min() < 0 or codes.max() > 255:
+            # out of byte range somewhere: only the padding may be
+            inside = np.arange(codes.shape[1]) < lens[:, None]
+            vals = codes[inside]
+            if vals.size and (vals.min() < 0 or vals.max() > 255):
+                return 32
+            codes = np.where(inside, codes, ord("A"))
+        if acgt:
+            ok = _ACGT.take(codes.astype(np.uint8))
+            # each row's first code outside ACGT lies past its length
+            acgt = bool((ok.all(axis=1) | (ok.argmin(axis=1) >= lens)).all())
+    return 2 if acgt else 8
+
+
 def problem_bounds(pen, plens: np.ndarray, tlens: np.ndarray,
                    edit_frac: Optional[float], s_max: Optional[int] = None,
                    k_max: Optional[int] = None) -> Tuple[int, int]:
@@ -354,7 +388,8 @@ class _Executable:
     def __init__(self, spec: BackendSpec, pen, s_max: int,
                  k_max: int, mesh: Optional[Mesh], output: str = "score",
                  heur=None, states: Tuple[str, str] = ("M", "M"),
-                 opts: Tuple[Tuple[str, object], ...] = ()):
+                 opts: Tuple[Tuple[str, object], ...] = (),
+                 char_bits: int = 32):
         self.s_max = s_max
         self.k_max = k_max
         self.compiled = False     # set by the first call
@@ -392,6 +427,12 @@ class _Executable:
         for kw, val in opts.items():
             if val is not None and _accepts_kw(backend_fn, kw):
                 extra[kw] = val
+        # characters compared per extend trip, for backends with a packed
+        # fetch (None: the backend has none)
+        self.chars_per_trip = None
+        if _accepts_kw(backend_fn, "char_bits"):
+            extra["char_bits"] = char_bits
+            self.chars_per_trip = 32 // char_bits
         # Only pass heur when pruning is actually requested, so
         # heuristic-unaware plug-in backends keep serving exact alignment.
         if not heur.exact:
@@ -479,9 +520,10 @@ class AlignmentEngine:
         ``band_cap`` (compacting-band ring width on ring/kernel/shardmap;
         ``"auto"`` derives it from the active heuristic's pruning radius
         via ``heur.band_cap`` — exact alignment stays full-width), plus
-        ``block_pairs`` / ``gather`` / ``ext_stride`` on the kernel
-        backend.  Unknown keys raise ``ValueError`` here, not at align
-        time.
+        ``block_pairs`` / ``gather`` on the kernel backend.  Unknown keys
+        raise ``ValueError`` here, not at align time, and so does
+        ``char_bits``: the kernel's fetch width follows each ticket's codes
+        (:func:`fetch_char_bits`).
     """
 
     def __init__(self, pen=DEFAULT, *, backend: str = "ring",
@@ -498,6 +540,9 @@ class AlignmentEngine:
                  backend_opts: Optional[Dict[str, object]] = None):
         spec = get_backend(backend)
         self.backend_opts = dict(backend_opts or {})
+        if "char_bits" in self.backend_opts:
+            raise ValueError("char_bits is not a backend option: it is "
+                             "derived from the codes of each submission")
         for kw in sorted(self.backend_opts):
             if not any(_accepts_kw(f, kw) for f in spec.callables()):
                 raise ValueError(
@@ -684,9 +729,13 @@ class AlignmentEngine:
     def _executable_for(self, pshape: tuple, tshape: tuple, s_max: int,
                         k_max: int, output: str = "score",
                         pen=None, heur=None,
-                        states: Tuple[str, str] = ("M", "M")
+                        states: Tuple[str, str] = ("M", "M"),
+                        char_bits: int = 32
                         ) -> Tuple["_Executable", bool]:
-        """Cached executable for one rectangular problem shape -> (exe, hit)."""
+        """Cached executable for one rectangular problem shape -> (exe, hit).
+
+        ``char_bits`` is the ticket's fetch class (:func:`fetch_char_bits`);
+        it keys the cache only where the backend's callable takes it."""
         spec = get_backend(self.backend)
         states = tuple(states)
         if output == "cigar" and states != ("M", "M") \
@@ -703,8 +752,11 @@ class AlignmentEngine:
         # backend opts too: each compiles a different recurrence /
         # pruning / seeding / blocking step.
         opts = tuple(sorted(self.backend_opts.items()))
+        if output == "bidir_meet" or not _accepts_kw(
+                spec.variant(output, pen.kind), "char_bits"):
+            char_bits = 32
         key = (spec, pen, heur, pshape, tshape, s_max, k_max, output, states,
-               opts)
+               opts, char_bits)
         exe = self._cache.get(key)
         if exe is not None:
             obs_metrics.counter("engine_cache_hits_total",
@@ -718,7 +770,7 @@ class AlignmentEngine:
                 "backend": spec.name, "shape": list(pshape),
                 "s_max": s_max, "k_max": k_max, "output": output})
         exe = _Executable(spec, pen, s_max, k_max, self.mesh, output, heur,
-                          states, opts)
+                          states, opts, char_bits)
         self._cache[key] = exe
         return exe, False
 

@@ -60,13 +60,13 @@ from repro.core import cigar as cigar_mod
 from repro.core.engine import (COMPILE_SECONDS, AlignmentEngine, BucketInfo,
                                EngineResult, EngineStats, Seq, _fit_width,
                                _pad_rows, _quantize_rows, _round_up,
-                               pack_batch)
+                               fetch_char_bits, pack_batch)
 from repro.obs import metrics as obs_metrics
 from repro.obs import record as obs_record
 from repro.obs import trace as obs_trace
 
-__all__ = ["AlignmentSession", "SessionStats", "Ticket", "WAVE_COUNTERS",
-           "WAVE_SPANS", "run_streamed"]
+__all__ = ["AlignmentSession", "FETCH_PAIRS", "SessionStats", "Ticket",
+           "WAVE_COUNTERS", "WAVE_SPANS", "run_streamed"]
 
 # The spans each wave opens (pack, then dispatch with a compile inside it
 # on a cache miss; wait, gather and, for CIGARs, traceback at retirement)
@@ -77,6 +77,11 @@ WAVE_SPANS = ("wave.pack", "wave.dispatch", "wave.compile", "wave.wait",
               "wave.gather", "wave.traceback")
 WAVE_COUNTERS = ("kernel_pairs_total", "kernel_score_steps_total",
                  "kernel_extend_trips_total", COMPILE_SECONDS)
+# Pairs retired from waves of a backend with a packed extend fetch, one
+# counter per fetch width (characters compared per trip: 16 for ACGT, 4
+# for other byte codes, 1 for wider codes), beside kernel_pairs_total.
+FETCH_PAIRS = {c: f"kernel_pairs_chars_per_trip_{c}_total"
+               for c in (16, 4, 1)}
 
 
 @dataclasses.dataclass
@@ -141,6 +146,7 @@ class Ticket:
                       if output == "bidir_meet" else None)
         self._starget = None             # [n] known costs for meet waves
         self._p = self._t = self._plen = self._tlen = None
+        self.char_bits = 32   # the kernel's fetch class for these codes
         self._outstanding = n_pairs      # rows without a final score yet
         self._recovery_rows: List[np.ndarray] = []   # overflow awaiting re-run
         self._steps = 0
@@ -170,6 +176,7 @@ class _Wave:
     recovery: bool
     pc: Optional[np.ndarray] = None   # padded codes, kept only for CIGAR
     tc: Optional[np.ndarray] = None   # waves (packed-backtrace replay)
+    chars_per_trip: Optional[int] = None   # the executable's fetch width
 
 
 class AlignmentSession:
@@ -341,6 +348,10 @@ class AlignmentSession:
                 ticket._t = np.asarray(t)
                 ticket._plen = np.asarray(plen, np.int32)
                 ticket._tlen = np.asarray(tlen, np.int32)
+                # once per ticket: every wave of it, recovery re-runs
+                # included, fetches at this width
+                ticket.char_bits = fetch_char_bits(
+                    ticket._p, ticket._plen, ticket._t, ticket._tlen)
                 if _starget is not None:
                     ticket._starget = np.asarray(_starget, np.int32)
                 if tv == "bidir" and out == "cigar" and not _internal:
@@ -417,7 +428,8 @@ class AlignmentSession:
             exe, hit = eng._executable_for(pc.shape, tc.shape, s_max, k_max,
                                            ticket.output, pen=ticket.pen,
                                            heur=ticket.heur,
-                                           states=ticket.states)
+                                           states=ticket.states,
+                                           char_bits=ticket.char_bits)
             for st in (ticket.stats, self.stats):
                 if hit:
                     st.cache_hits += 1
@@ -470,7 +482,8 @@ class AlignmentSession:
         keep = ticket.output == "cigar"
         self._inflight.append(_Wave(ticket, rows, res, plc, tlc, k_max,
                                     recovery, pc=pc if keep else None,
-                                    tc=tc if keep else None))
+                                    tc=tc if keep else None,
+                                    chars_per_trip=exe.chars_per_trip))
         self.stats.n_waves += 1
         self.stats.peak_inflight = max(self.stats.peak_inflight,
                                        len(self._inflight))
@@ -533,6 +546,12 @@ class AlignmentSession:
         obs_metrics.counter("kernel_score_steps_total",
                             "score-loop steps of retired waves"
                             ).inc(steps)
+        if wave.chars_per_trip is not None:
+            obs_metrics.counter(
+                FETCH_PAIRS[wave.chars_per_trip],
+                f"real pair rows retired from kernel waves comparing "
+                f"{wave.chars_per_trip} characters per extend trip"
+            ).inc(len(wave.rows))
         if trips is not None:
             for st in (ticket.stats, self.stats):
                 st.n_ext_trips += trips

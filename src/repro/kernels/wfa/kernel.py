@@ -24,10 +24,15 @@ Character fetch during extension is selected by the static ``gather`` mode:
   ~25x faster.  The wrapper defaults to ``index`` under ``interpret`` and
   ``onehot`` when compiled.
 
-``ext_stride`` fetches several consecutive characters per extend trip
-(index mode): each trip gathers ``C`` chars of both sequences, takes the
-cumulative-AND of the matches along the stride, and advances each lane by
-its matched prefix — long match runs finish in ``len/C`` trips.
+The sequences arrive as **packed words** (built by the ops wrapper):
+word ``l`` holds characters ``l .. l + C - 1`` at ``char_bits`` bits each,
+character ``l`` in the lowest field, with ``C = 32 // char_bits`` (16 for
+2-bit DNA, 4 for bytes, 1 for raw int32 codes).  One extend trip fetches
+the text word at ``M`` and the pattern word at ``M - k``; the trailing
+zero fields of their XOR are the matched prefix, so each lane advances by
+up to ``C`` characters per trip (the WFA2-lib word compare, with a field
+count in place of a count of trailing zero bits).  Every lane still stops
+at its maximal offset, so scores and trace codes do not depend on ``C``.
 
 ``band_cap`` switches on the **compacting band** (the in-kernel counterpart
 of ``core.wavefront``'s ``band_cap``): rings are allocated at a compact
@@ -98,38 +103,52 @@ def _gather_chars(seq, idx, mode: str):
 
     idx is pre-clipped here; out-of-range lanes read junk that the caller's
     validity mask discards.  ``onehot`` is the VPU compare-and-reduce
-    formulation (TPUs lack per-lane gather); ``index`` is a real gather for
-    interpret mode, where the body runs as plain jax ops.
+    formulation (TPUs lack per-lane gather), taken 128 diagonal lanes at a
+    time so that its [BP, 128, L] intermediate, not a [BP, K, L] one, sets
+    its scoped VMEM; ``index`` is a real gather for interpret mode, where
+    the body runs as plain jax ops.
     """
     L = seq.shape[1]
     idx_c = jnp.clip(idx, 0, L - 1)
     if mode == "index":
         return jnp.take_along_axis(seq, idx_c, axis=1)
     BP, K = idx.shape
-    l_iota = lax.broadcasted_iota(jnp.int32, (BP, K, L), 2)
-    hit = (l_iota == idx_c[:, :, None])
-    return jnp.sum(jnp.where(hit, seq[:, None, :], 0), axis=2)
+    parts = []
+    for k0 in range(0, K, 128):
+        part = idx_c[:, k0:k0 + 128]
+        l_iota = lax.broadcasted_iota(jnp.int32, part.shape + (L,), 2)
+        hit = (l_iota == part[:, :, None])
+        parts.append(jnp.sum(jnp.where(hit, seq[:, None, :], 0), axis=2))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
-def _gather_strided(seq, idx, C: int):
-    """seq [BP, L], idx [BP, K] -> seq[b, idx[b, k] + c] as [BP, K, C].
+def _zero_fields(x, char_bits: int):
+    """Trailing zero ``char_bits``-wide fields of each int32 in x: 0 .. C,
+    C = 32 // char_bits where x == 0.
 
-    One flattened take_along_axis for all C consecutive characters
-    (index-gather mode only)."""
-    BP, L = seq.shape
-    K = idx.shape[1]
-    cidx = lax.broadcasted_iota(jnp.int32, (BP, K, C), 2)
-    flat = jnp.clip(idx[:, :, None] + cidx, 0, L - 1).reshape(BP, K * C)
-    return jnp.take_along_axis(seq, flat, axis=1).reshape(BP, K, C)
+    A binary search of masks and compares (log2 C rounds), since a bit
+    count may not lower in Mosaic.  The shifts are logical: the sign bit
+    is a data bit."""
+    C = 32 // char_bits
+    n = jnp.zeros_like(x)
+    y = x
+    half = 16
+    while half >= char_bits:
+        z = (y & ((1 << half) - 1)) == 0
+        n = n + jnp.where(z, half // char_bits, 0)
+        y = jnp.where(z, lax.shift_right_logical(y, jnp.full_like(y, half)),
+                      y)
+        half //= 2
+    return jnp.where(x == 0, C, n)
 
 
 def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
-                 gather: str, ext_stride: int, band: bool):
+                 gather: str, char_bits: int, band: bool):
     x, o, e = model.x, model.o, model.e
     W = model.window
     affine = model.kind == "affine"
     n_bt = (3 if affine else 1) if trace else 0
-    C = ext_stride if gather == "index" else 1
+    C = 32 // char_bits                     # characters per packed word
     kc_full = k_pad // 2                     # absolute diagonal center
 
     def kernel(p_ref, t_ref, pl_ref, tl_ref, out_ref, steps_ref, trips_ref,
@@ -157,32 +176,27 @@ def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
 
         def extend(M, ks):
             """-> (extended M, extend trips taken: one per lock-step
-            fetch over the block's [BP, Kc] front)."""
+            word fetch over the block's [BP, Kc] front)."""
             def trip(st):
                 M, _, n = st
                 n = n + 1
                 v = M - ks
-                base = (M > _THRESH)
-                if C == 1:
-                    can = (base & (M >= 0) & (M < tlen)
-                           & (v >= 0) & (v < plen))
-                    tc = _gather_chars(txt, M, gather)
-                    pc = _gather_chars(pat, v, gather)
-                    adv = (can & (tc == pc)).astype(jnp.int32)
-                    return M + adv, jnp.any(adv == 1), n
-                tcs = _gather_strided(txt, M, C)
-                pcs = _gather_strided(pat, v, C)
-                cidx = lax.broadcasted_iota(jnp.int32, (BP, Kc, C), 2)
-                h3 = M[:, :, None] + cidx
-                v3 = v[:, :, None] + cidx
-                ok = (base[:, :, None]
-                      & (h3 >= 0) & (h3 < tlen[:, :, None])
-                      & (v3 >= 0) & (v3 < plen[:, :, None])
-                      & (tcs == pcs))
-                # matched prefix length along the stride
-                adv = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=2),
-                              axis=2)
-                return M + adv, jnp.any(adv >= C), n
+                can = ((M > _THRESH) & (M >= 0) & (M < tlen)
+                       & (v >= 0) & (v < plen))
+                tw = _gather_chars(txt, M, gather)
+                pw = _gather_chars(pat, v, gather)
+                # The onehot fetch's lane reduce leaves the words one per
+                # sublane row, 8 to a vreg.  Selecting on `can`, laid out
+                # like the front (1,024 to a vreg), moves the XOR into the
+                # front's layout once, so the field count and the clamp
+                # below run on that; -1 matches no field.
+                x = jnp.where(can, tw ^ pw, -1)
+                # matched prefix of the two words, cut at either end
+                run = jnp.minimum(jnp.minimum(tlen - M, plen - v),
+                                  _zero_fields(x, char_bits))
+                adv = jnp.where(can, run, 0)
+                # a lane that matched a whole word may match further
+                return M + adv, jnp.any(adv == C), n
 
             st = trip((M, jnp.bool_(True), jnp.int32(0)))
             M, _, n = lax.while_loop(lambda st: st[1], trip, st)
@@ -360,13 +374,15 @@ def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
 @functools.partial(jax.jit, static_argnames=("pen", "s_max", "k_pad",
                                              "block_pairs", "interpret",
                                              "trace", "heur", "gather",
-                                             "ext_stride", "band_cap"))
+                                             "char_bits", "band_cap"))
 def wfa_pallas(pattern, text, plen, tlen, *, pen, s_max: int,
                k_pad: int, block_pairs: int = 8, interpret: bool = True,
                trace: bool = False, heur=None, gather=None,
-               ext_stride: int = 1, band_cap=None):
-    """pattern/text [B, L*] int32 (B % block_pairs == 0, L* % 128 == 0),
-    plen/tlen [B, 1] int32, k_pad % 128 == 0 is the padded diagonal count.
+               char_bits: int = 32, band_cap=None):
+    """pattern/text [B, L*] int32 packed words of ``char_bits``-bit
+    characters (B % block_pairs == 0, L* % 128 == 0; with ``char_bits``
+    32 the words are the codes themselves), plen/tlen [B, 1] int32 in
+    characters, k_pad % 128 == 0 is the padded diagonal count.
     -> (score [B, 1] int32, steps [B, 1] int32, trips [B, 1] int32), the
     last two the block's score steps and extend trips on each of its rows;
     with ``trace`` additionally
@@ -374,9 +390,9 @@ def wfa_pallas(pattern, text, plen, tlen, *, pen, s_max: int,
     affine models, one for linear).
 
     ``gather`` (``"index"``/``"onehot"``; None = index under interpret,
-    onehot compiled), ``ext_stride`` (chars fetched per extend trip, index
-    mode) and ``band_cap`` (compact ring width, lane-aligned by the ops
-    wrapper; None = full width) are static — see the module docstring.
+    onehot compiled), ``char_bits`` (2, 8 or 32) and ``band_cap`` (compact
+    ring width, lane-aligned by the ops wrapper; None = full width) are
+    static — see the module docstring.
     """
     B, Lp = pattern.shape
     Lt = text.shape[1]
@@ -399,7 +415,7 @@ def wfa_pallas(pattern, text, plen, tlen, *, pen, s_max: int,
                 f"{SCOPED_VMEM_BYTES >> 20} MiB (bucket [{B}, {Lp}]); "
                 f"align this bucket on the 'ring' backend")
     kernel, W, affine = _make_kernel(model, heur, s_max, k_pad, trace,
-                                     gather, max(int(ext_stride), 1), band)
+                                     gather, char_bits, band)
     grid = (B // BP,)
     n_rings = 3 if affine else 1
 

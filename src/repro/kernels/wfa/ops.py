@@ -12,12 +12,17 @@ Tuning knobs (all optional, threaded from
   auto-default (8: one int32 sublane tile on TPU, and the measured best in
   interpret mode, where a small block keeps the per-block early exit
   effective).
-* ``gather`` — extension character fetch, ``"index"``/``"onehot"``
+* ``gather`` — extension word fetch, ``"index"``/``"onehot"``
   (default: index under interpret, onehot compiled — see ``kernel.py``).
-* ``ext_stride`` — characters fetched per extend trip (index mode).
 * ``band_cap`` — compacting-band width; lane-aligned here (rounded up to
   128) before reaching the kernel, so the compact rings stay legal TPU
   tiles.  None = full width.
+
+``char_bits`` is not a knob: the session derives it from the codes it
+is given (``core.engine.fetch_char_bits``).  The wrapper packs both
+sequence arrays into words of ``32 // char_bits`` characters inside the
+same jit (:func:`pack_words`), so the extend loop compares that many
+characters per trip.
 """
 from __future__ import annotations
 
@@ -87,6 +92,33 @@ def _prep(pattern, text, plen, tlen, block_pairs):
     return pattern, text, plen2, tlen2, B
 
 
+CHAR_BITS = (2, 8, 32)
+
+
+def pack_words(seq, char_bits: int):
+    """[B, L] int32 codes -> [B, L] int32 words of ``C = 32 // char_bits``
+    characters: word ``l`` holds characters ``l .. l + C - 1``, character
+    ``l`` in the lowest field, positions past ``L`` as 0.
+
+    ``char_bits`` 2 takes the field ``(c >> 1) & 3``, one-to-one on the
+    ASCII codes A, C, G, T (0, 1, 3, 2); 8 takes ``c`` itself (0..255);
+    32 returns the codes unchanged (one character per word).
+    """
+    if char_bits not in CHAR_BITS:
+        raise ValueError(f"char_bits must be one of {CHAR_BITS}, "
+                         f"got {char_bits!r}")
+    if char_bits == 32:
+        return seq
+    C = 32 // char_bits
+    field = (seq >> 1) & 3 if char_bits == 2 else seq
+    L = seq.shape[1]
+    ext = jnp.pad(field, ((0, 0), (0, C - 1)))
+    word = field
+    for c in range(1, C):
+        word = word | jnp.left_shift(ext[:, c:c + L], char_bits * c)
+    return word
+
+
 def _block_sums(steps, trips, bp: int):
     """The kernel's per-block loop counters ([B, 1], one value repeated
     over each block's rows) summed over grid blocks -> two [] int32."""
@@ -96,14 +128,16 @@ def _block_sums(steps, trips, bp: int):
 def wfa_align(pattern, text, plen, tlen, *, pen, s_max: int,
               k_max: int, block_pairs: Optional[int] = None,
               interpret: Optional[bool] = None, heur=None,
-              gather: Optional[str] = None, ext_stride: int = 1,
+              gather: Optional[str] = None, char_bits: int = 32,
               band_cap: Optional[int] = None, counts: bool = False):
     """Batched WFA scores via the Pallas kernel.
 
     pattern/text: [B, L*] int; plen/tlen: [B] int.  Returns [B] int32 costs
     (-1 where the optimal cost exceeds ``s_max``); with ``counts``,
     ``(score, steps, trips)``: the score steps and extend trips of every
-    grid block, summed.  ``pen`` may be any
+    grid block, summed.  ``char_bits`` (2, 8 or 32) packs the codes
+    into words of ``32 // char_bits`` characters (:func:`pack_words`);
+    the caller vouches that they fit.  ``pen`` may be any
     ``PenaltyModel`` (or a legacy ``Penalties`` triple) and ``heur`` an
     optional ``WavefrontHeuristic``; both specialize the kernel statically.
     ``interpret`` defaults to True off-TPU (CPU validation) and False on
@@ -116,10 +150,10 @@ def wfa_align(pattern, text, plen, tlen, *, pen, s_max: int,
     k_pad = _round_up(2 * k_max + 1, LANE)
 
     score, steps, trips = wfa_pallas(
-        pattern, text, plen2, tlen2, pen=pen, s_max=s_max, k_pad=k_pad,
-        block_pairs=bp, interpret=interpret,
-        heur=scoring.as_heuristic(heur), gather=gather,
-        ext_stride=ext_stride, band_cap=_band_lanes(band_cap, k_pad))
+        pack_words(pattern, char_bits), pack_words(text, char_bits), plen2,
+        tlen2, pen=pen, s_max=s_max, k_pad=k_pad, block_pairs=bp,
+        interpret=interpret, heur=scoring.as_heuristic(heur), gather=gather,
+        char_bits=char_bits, band_cap=_band_lanes(band_cap, k_pad))
     if counts:
         return (score[:B, 0],) + _block_sums(steps, trips, bp)
     return score[:B, 0]
@@ -128,7 +162,7 @@ def wfa_align(pattern, text, plen, tlen, *, pen, s_max: int,
 def wfa_align_trace(pattern, text, plen, tlen, *, pen, s_max: int,
                     k_max: int, block_pairs: Optional[int] = None,
                     interpret: Optional[bool] = None, heur=None,
-                    gather: Optional[str] = None, ext_stride: int = 1,
+                    gather: Optional[str] = None, char_bits: int = 32,
                     band_cap: Optional[int] = None, counts: bool = False):
     """Batched WFA scores *plus* packed backtrace via the Pallas kernel.
 
@@ -149,10 +183,11 @@ def wfa_align_trace(pattern, text, plen, tlen, *, pen, s_max: int,
     k_pad = _round_up(2 * k_max + 1, LANE)
 
     out = wfa_pallas(
-        pattern, text, plen2, tlen2, pen=pen, s_max=s_max, k_pad=k_pad,
-        block_pairs=bp, interpret=interpret, trace=True,
-        heur=scoring.as_heuristic(heur), gather=gather,
-        ext_stride=ext_stride, band_cap=_band_lanes(band_cap, k_pad))
+        pack_words(pattern, char_bits), pack_words(text, char_bits), plen2,
+        tlen2, pen=pen, s_max=s_max, k_pad=k_pad, block_pairs=bp,
+        interpret=interpret, trace=True, heur=scoring.as_heuristic(heur),
+        gather=gather, char_bits=char_bits,
+        band_cap=_band_lanes(band_cap, k_pad))
     score, steps, trips, m_bt, *gaps = out
     i_bt, d_bt = (g[:, :B, :] for g in gaps) if gaps else (None, None)
     res = (score[:B, 0], m_bt[:, :B, :], i_bt, d_bt)

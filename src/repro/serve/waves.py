@@ -173,14 +173,17 @@ class WaveFormer:
             row += hi - lo
         n_real = row
         if self.pad_to_full and n_real < self.wave_pairs:
-            # self-aligning pad rows (zeros vs zeros, full bucket length):
+            # self-aligning pad rows ("A"s vs "A"s, full bucket length):
             # they land in the same length bucket as the real rows, so the
             # padded wave is the SAME executable shape as a full one —
             # zero retraces even for a deadline-flushed lonely request.
+            # "A" keeps an all-ACGT wave in the kernel's 16-base fetch
+            # class (core.engine.fetch_char_bits), as a full wave is.
             n_pad = self.wave_pairs - n_real
             pad_len = min(lmax, width)
-            ps.append(np.zeros((n_pad, width), np.int32))
-            ts.append(np.zeros((n_pad, width), np.int32))
+            pad = np.full((n_pad, width), ord("A"), np.int32)
+            ps.append(pad)
+            ts.append(pad)
             plens.append(np.full((n_pad,), pad_len, np.int32))
             tlens.append(np.full((n_pad,), pad_len, np.int32))
         self.n_formed += 1

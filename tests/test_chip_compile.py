@@ -3,7 +3,9 @@
 Each test lowers a kernel variant the engine dispatches at the paper's
 widths (2,048 pairs, a 128-wide bucket) with ``interpret=False`` for one
 chip of a described ``v5e:2x2`` topology, and checks the Mosaic kernel is
-in the compiled program.  Nothing runs; the compiler refuses here what it
+in the compiled program.  Each runs at every packed-fetch width the
+session can pick (``char_bits`` 2, 8 and 32: 16, 4 and 1 characters per
+extend trip), since each lowers its own field count and shifts.  Nothing runs; the compiler refuses here what it
 would refuse on the chip (VMEM, unsupported primitives).
 """
 import functools
@@ -20,6 +22,7 @@ from repro.kernels.wfa import ops as kops
 
 PAIRS, WIDTH = 2048, 128
 AFFINE = GapAffine(4, 6, 2)
+CHAR_BITS = pytest.mark.parametrize("char_bits", [2, 8, 32])
 
 
 @pytest.fixture(scope="module")
@@ -77,22 +80,26 @@ def _compile(one_chip, fn, **kw):
         "affine-trace-optimistic", "affine-trace-exact",
         "linear-score-optimistic", "edit-score-optimistic",
         "edit-trace-exact"])
+@CHAR_BITS
 def test_paper_regime_kernel_compiles(one_chip, no_persistent_cache, pen,
-                                      edit_frac, exact, trace):
+                                      edit_frac, exact, trace, char_bits):
     s_max, k_max = _bounds(pen, edit_frac, exact)
     fn = kops.wfa_align_trace if trace else kops.wfa_align
-    hlo = _compile(one_chip, fn, pen=pen, s_max=s_max, k_max=k_max)
+    hlo = _compile(one_chip, fn, pen=pen, s_max=s_max, k_max=k_max,
+                   char_bits=char_bits)
     assert "tpu_custom_call" in hlo
 
 
-def test_compacting_band_kernel_compiles(one_chip, no_persistent_cache):
+@CHAR_BITS
+def test_compacting_band_kernel_compiles(one_chip, no_persistent_cache,
+                                         char_bits):
     heur = AdaptiveBand()
     s_max, k_max = _bounds(AFFINE, 0.04, True)
     cap = heur.band_cap(2 * k_max + 1)
     assert kops._band_lanes(cap, kops._round_up(2 * k_max + 1, 128))
     for fn in (kops.wfa_align, kops.wfa_align_trace):
         hlo = _compile(one_chip, fn, pen=AFFINE, s_max=s_max, k_max=k_max,
-                       heur=heur, band_cap=cap)
+                       heur=heur, band_cap=cap, char_bits=char_bits)
         assert "tpu_custom_call" in hlo
 
 

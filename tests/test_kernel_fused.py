@@ -150,15 +150,6 @@ def test_gather_mode_invariance(pen):
     np.testing.assert_array_equal(idx, oh)
 
 
-def test_ext_stride_invariance():
-    P, plen, T, tlen, s_max, k_max = _pairs(8, 48, 0.06, 26)
-    one = np.asarray(kops.wfa_align(P, T, plen, tlen, pen=DEFAULT,
-                                    s_max=s_max, k_max=k_max, ext_stride=1))
-    four = np.asarray(kops.wfa_align(P, T, plen, tlen, pen=DEFAULT,
-                                     s_max=s_max, k_max=k_max, ext_stride=4))
-    np.testing.assert_array_equal(one, four)
-
-
 # -- device-resident BiWFA meet ---------------------------------------------
 
 
