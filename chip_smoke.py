@@ -10,8 +10,10 @@ E = 2% and E = 4%, in score and in CIGAR mode, each verified against the
 Gotoh oracle; proof that the kernel was compiled for the chip and not
 interpreted; ``kernel`` against ``ring`` scores on the same pairs; and the
 alignment server (``repro.launch.serve_align``) on the ``kernel`` backend.
-``--chips 4`` runs the ``shardmap`` backend over the host's four-device
-mesh against a one-device ``ring`` run, and nothing else.
+``--chips 4`` runs the ``shardmap`` backend (the kernel per device) over
+all of the host's devices against a one-device ``kernel`` run and the
+Gotoh oracle, checks that the sharded executable holds the compiled
+kernel, and nothing else.
 
 Each phase prints a ``[smoke]`` line and any failure exits non-zero.  The
 pairs/s, compile times and cache hits printed on the way are smoke
@@ -194,24 +196,25 @@ def phase_mesh(pairs: int) -> None:
     from repro.configs import wfa_paper
     from repro.core.engine import AlignmentEngine
     from repro.data.reads import ReadPairSpec, generate_pairs
-    from repro.launch.mesh import make_host_mesh
 
     n_dev = jax.device_count()
-    wave = str(wfa_paper.pairs_per_device * n_dev)
+    wave = wfa_paper.pairs_per_device * n_dev
     common = ["--pairs", str(pairs), "--seed", "11", "--edit-frac", "0.02",
               "--verify", "256", "--read-len", "100", "--mode", "stream",
-              "--chunk-pairs", wave]
+              "--chunk-pairs", str(wave)]
     sharded, _ = _align(["--backend", "shardmap"] + common)
-    single, _ = _align(["--backend", "ring"] + common)
+    single, _ = _align(["--backend", "kernel"] + common)
     n_diff = int((sharded != single).sum())
-    check(n_diff == 0, f"shardmap and one-device ring disagree on "
+    check(n_diff == 0, f"shardmap and one-device kernel disagree on "
                        f"{n_diff} pairs")
 
+    # no mesh given: the engine spans every device of the host
     eng = AlignmentEngine(wfa_paper.pen, backend="shardmap",
-                          edit_frac=wfa_paper.edit_frac,
-                          mesh=make_host_mesh(), chunk_pairs=int(wave))
+                          edit_frac=wfa_paper.edit_frac, chunk_pairs=wave)
+    check(eng.n_workers == n_dev,
+          f"the engine's mesh spans {eng.n_workers} of {n_dev} devices")
     P, plen, T, tlen = generate_pairs(ReadPairSpec(
-        n_pairs=int(wave), read_len=wfa_paper.read_len,
+        n_pairs=wave, read_len=wfa_paper.read_len,
         edit_frac=wfa_paper.edit_frac, seed=11))
     eng.align_packed(P, plen, T, tlen)
     key, exe = next(iter(eng._cache.items()))
@@ -219,19 +222,25 @@ def phase_mesh(pairs: int) -> None:
     dev = eng._device_put(np.zeros(key[3], np.int32),
                           np.zeros(key[4], np.int32),
                           np.zeros(rows, np.int32), np.zeros(rows, np.int32))
+    hlo = exe.fn.lower(*dev).compile().as_text()
+    check("tpu_custom_call" in hlo,
+          f"no tpu_custom_call in the sharded executable {list(key[3])}")
     res = exe.call(*dev)
     res.score.block_until_ready()
-    for name, arr in zip(("pattern", "text", "plen", "tlen", "score"),
-                         dev + (res.score,)):
+    for name, arr in zip(("pattern", "text", "plen", "tlen", "score",
+                          "steps", "trips"),
+                         dev + (res.score, res.n_steps, res.n_ext_trips)):
         shards = arr.addressable_shards
         devices = {s.device for s in shards}
         check(len(devices) == n_dev,
               f"{name} lives on {len(devices)} of {n_dev} devices")
-        check(all(s.data.shape[0] == rows // n_dev for s in shards),
+        check(all(s.data.shape[0] == arr.shape[0] // n_dev for s in shards),
               f"{name} is not split evenly over the devices")
-    say(f"mesh: shardmap over {n_dev} devices == one-device ring on all "
-        f"{pairs} pairs; inputs and scores of a {rows}-row wave split "
-        f"{rows // n_dev} rows per device on all {n_dev} devices")
+    say(f"mesh: shardmap over {n_dev} devices == one-device kernel on all "
+        f"{pairs} pairs, 256 verified against Gotoh; the sharded executable "
+        f"holds tpu_custom_call; inputs and scores of a {rows}-row wave "
+        f"split {rows // n_dev} rows per device, loop counters one per "
+        f"device")
 
 
 def main(argv=None) -> int:
@@ -247,7 +256,7 @@ def main(argv=None) -> int:
         device = phase_device(args.chips)
         say(f"compile cache: {cache_dir}")
         if args.chips == 4:
-            phase_mesh(pairs=1 << 14)
+            phase_mesh(pairs=1 << 16)
         else:
             phase_batch(pairs=1 << 18, verify=256, log=log)
             phase_compiled(pairs=2048)

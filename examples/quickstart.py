@@ -5,8 +5,8 @@ One object covers every alignment scenario:
 * ``AlignmentEngine(backend=...)`` picks an execution strategy from the
   backend registry — ``"ref"`` (pure-jnp reference), ``"ring"``
   (rolling-window throughput), ``"kernel"`` (Pallas TPU kernel),
-  ``"shardmap"`` (per-shard termination on a mesh) — and plug-ins can
-  ``register_backend`` their own without touching core code.
+  ``"shardmap"`` (the Pallas kernel per shard over every device) — and
+  plug-ins can ``register_backend`` their own without touching core code.
 * Every call picks an output mode: ``output="score"`` (default) or
   ``output="cigar"`` — full alignments on *any* built-in backend, via the
   packed 2-bit backtrace (``ring``/``kernel``/``shardmap``) or the full

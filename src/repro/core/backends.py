@@ -41,7 +41,8 @@ Every backend serves two *output modes* (the engine's
   simply "has a trace variant"; score-only plug-ins may omit it.
 
 Backends that shard over a device mesh set ``needs_mesh`` and receive the
-engine's ``mesh`` as a keyword.  Two further hooks tune how the engine
+engine's ``mesh`` as a keyword (an engine given none spans every local
+device).  Two further hooks tune how the engine
 *drives* a backend (both optional):
 
 * ``donate_args`` — positional indices of ``(pattern, text, plen, tlen)``
@@ -64,10 +65,11 @@ heuristic):
                    packed backtrace alongside the rings
 * ``"kernel"``   — the Pallas TPU kernel (interpret=True on CPU); trace
                    variant OR-accumulates packed words in VMEM
-* ``"shardmap"`` — ring solver inside ``shard_map`` (per-shard termination,
+* ``"shardmap"`` — the ``kernel`` backend per shard inside ``shard_map``
+                   over every device of the mesh (per-shard termination,
                    zero collectives — the paper's "no inter-DPU
-                   communication"); trace variant runs the packed solver
-                   per shard
+                   communication"); each shard returns its own loop
+                   counters, and its trace variant its own packed words
 """
 from __future__ import annotations
 
@@ -75,8 +77,9 @@ import dataclasses
 import inspect
 from typing import Callable, Dict, List, Optional, Tuple
 
-import jax.numpy as jnp
+import jax
 
+from repro.core import scoring
 from repro.core import wavefront as wf
 
 ALL_MODELS = ("affine", "linear")
@@ -309,22 +312,54 @@ def _kernel_backend(pattern, text, plen, tlen, *, pen, s_max, k_max,
     return wf.WFAResult(score, None, None, None, steps, n_ext_trips=trips)
 
 
+def _per_shard(kernel_fn, n_bt: int, mesh, pattern, text, plen, tlen, **kw):
+    """A ``kernel`` backend callable on each shard under ``shard_map``.
+
+    The pair axis of every input and output is split over all of
+    ``mesh``'s axes (``engine.pair_sharding``); each shard's steps and
+    trips come back as its row of a [shards] array, and the first
+    ``n_bt`` packed backtrace planes ([words, B, K]) split on their pair
+    axis.  No collectives: each shard's loops end with its own pairs.
+    """
+    from jax.sharding import PartitionSpec as P
+    names = tuple(mesh.axis_names)
+    rows, cols = P(names), P(names, None)
+
+    def local(p, t, pl, tl):
+        r = kernel_fn(p, t, pl, tl, **kw)
+        return ((r.score, r.n_steps[None], r.n_ext_trips[None])
+                + (r.m_bt, r.i_bt, r.d_bt)[:n_bt])
+
+    # the kernel's loops are per shard by construction, so the
+    # varying-manual-axes check is off
+    score, steps, trips, *bt = jax.shard_map(
+        local, mesh=mesh, in_specs=(cols, cols, rows, rows),
+        out_specs=(rows, rows, rows) + (P(None, names, None),) * n_bt,
+        check_vma=False)(pattern, text, plen, tlen)
+    return wf.WFAResult(score, None, None, None, steps,
+                        *(bt + [None] * (3 - n_bt)), n_ext_trips=trips)
+
+
 def _shardmap_trace(pattern, text, plen, tlen, *, pen, s_max, k_max, mesh,
-                    heur=None, band_cap=None):
-    score, m_bt, i_bt, d_bt = wf.wfa_trace_shardmap(
-        pattern, text, plen, tlen, pen=pen, s_max=s_max, k_max=k_max,
-        mesh=mesh, heur=heur, band_cap=band_cap)
-    return wf.WFAResult(score, None, None, None, jnp.int32(s_max),
-                        m_bt, i_bt, d_bt)
+                    heur=None, block_pairs=None, gather=None, char_bits=32,
+                    band_cap=None):
+    # linear models record one M plane, affine ones M, I and D
+    n_bt = 3 if scoring.as_model(pen).kind == "affine" else 1
+    return _per_shard(_kernel_trace, n_bt, mesh, pattern, text, plen, tlen,
+                      pen=pen, s_max=s_max, k_max=k_max, heur=heur,
+                      block_pairs=block_pairs, gather=gather,
+                      char_bits=char_bits, band_cap=band_cap)
 
 
 @register_backend("shardmap", needs_mesh=True, trace_variant=_shardmap_trace,
                   models=ALL_MODELS,
-                  doc="ring solver in shard_map: per-shard termination, "
-                      "zero collectives; per-shard packed backtrace")
+                  doc="the kernel backend per shard in shard_map: per-shard "
+                      "termination, zero collectives; per-shard loop "
+                      "counters and packed backtrace")
 def _shardmap_backend(pattern, text, plen, tlen, *, pen, s_max, k_max, mesh,
-                      heur=None, band_cap=None):
-    score = wf.wfa_scores_shardmap(pattern, text, plen, tlen, pen=pen,
-                                   s_max=s_max, k_max=k_max, mesh=mesh,
-                                   heur=heur, band_cap=band_cap)
-    return wf.WFAResult(score, None, None, None, jnp.int32(s_max))
+                      heur=None, block_pairs=None, gather=None, char_bits=32,
+                      band_cap=None):
+    return _per_shard(_kernel_backend, 0, mesh, pattern, text, plen, tlen,
+                      pen=pen, s_max=s_max, k_max=k_max, heur=heur,
+                      block_pairs=block_pairs, gather=gather,
+                      char_bits=char_bits, band_cap=band_cap)

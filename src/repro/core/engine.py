@@ -84,6 +84,7 @@ from repro.obs import trace as obs_trace
 from repro.core import wavefront as wf
 from repro.core.backends import BackendSpec, get_backend, _accepts_kw
 from repro.core.penalties import DEFAULT
+from repro.distributed.compat import make_mesh
 
 Seq = Union[str, bytes, np.ndarray]
 
@@ -510,7 +511,9 @@ class AlignmentEngine:
         Per-call ``heuristic=`` overrides.
     with_cigar : deprecated spelling of ``output="cigar"`` (kept for
         compatibility; per-call ``output=`` is the API).
-    mesh : device mesh for scatter/gather (and for ``needs_mesh`` backends).
+    mesh : device mesh for scatter/gather (and for ``needs_mesh`` backends,
+        which span every local device on a 1-D ``("pairs",)`` mesh when
+        given none).
     chunk_pairs : max pairs per device wave (the MRAM-capacity analogue).
     bucket_by_length : sort pairs into power-of-two length buckets.
     min_bucket_len : floor for bucket lengths (avoids tiny-shape churn).
@@ -561,7 +564,10 @@ class AlignmentEngine:
                 f"CIGAR output needs a backend with a trace variant; "
                 f"{backend!r} is score-only")
         if spec.needs_mesh and mesh is None:
-            raise ValueError(f"backend {backend!r} needs a device mesh")
+            # a sharding backend given no mesh splits every wave over all
+            # of this host's devices
+            mesh = make_mesh((jax.local_device_count(),), ("pairs",),
+                             devices=jax.local_devices())
         self.pen = scoring.as_model(pen)
         spec.variant("score", self.pen.kind)   # raises if model unsupported
         self.heuristic = scoring.as_heuristic(heuristic)

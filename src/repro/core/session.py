@@ -65,18 +65,23 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import record as obs_record
 from repro.obs import trace as obs_trace
 
-__all__ = ["AlignmentSession", "FETCH_PAIRS", "SessionStats", "Ticket",
-           "WAVE_COUNTERS", "WAVE_SPANS", "run_streamed"]
+__all__ = ["AlignmentSession", "FETCH_PAIRS", "SHARD_COUNTERS",
+           "SessionStats", "Ticket", "WAVE_COUNTERS", "WAVE_SPANS",
+           "run_streamed"]
 
-# The spans each wave opens (pack, then dispatch with a compile inside it
-# on a cache miss; wait, gather and, for CIGARs, traceback at retirement)
-# and the registry counters the wave path adds to.  Readers outside the
-# package look these names up here, so a rename shows as a missing name
-# rather than as an empty reading.
-WAVE_SPANS = ("wave.pack", "wave.dispatch", "wave.compile", "wave.wait",
-              "wave.gather", "wave.traceback")
+# The spans each wave opens (pack, then dispatch with the host-to-device
+# copy inside it, and a compile too on a cache miss; wait, gather and, for
+# CIGARs, traceback at retirement) and the registry counters the wave path
+# adds to.  Readers outside the package look these names up here, so a
+# rename shows as a missing name rather than as an empty reading.
+WAVE_SPANS = ("wave.pack", "wave.dispatch", "wave.put", "wave.compile",
+              "wave.wait", "wave.gather", "wave.traceback")
+# Waves split over more than one shard also add, per wave, the extend
+# trips of their slowest shard and the mean over their shards.
+SHARD_COUNTERS = ("kernel_shard_trips_max_total",
+                  "kernel_shard_trips_mean_total")
 WAVE_COUNTERS = ("kernel_pairs_total", "kernel_score_steps_total",
-                 "kernel_extend_trips_total", COMPILE_SECONDS)
+                 "kernel_extend_trips_total", COMPILE_SECONDS) + SHARD_COUNTERS
 # Pairs retired from waves of a backend with a packed extend fetch, one
 # counter per fetch width (characters compared per trip: 16 for ACGT, 4
 # for other byte codes, 1 for wider codes), beside kernel_pairs_total.
@@ -446,7 +451,8 @@ class AlignmentSession:
                                 args=args) as sp:
                 for fid in ticket.flows:
                     sp.flow_step(fid)
-                dev = eng._device_put(*arrays)
+                with obs_trace.span("wave.put", cat="wave", args=args):
+                    dev = eng._device_put(*arrays)
                 if self._sync:
                     jax.block_until_ready(dev)
                     t1 = time.perf_counter()
@@ -524,13 +530,16 @@ class AlignmentSession:
         for fid in ticket.flows:
             sp.flow_step(fid)
         # one batch of device-to-host copies, so the counters' reads
-        # overlap the scores' (breakpoint waves carry no trip counter)
-        full, steps, trips = jax.device_get(
+        # overlap the scores' (breakpoint waves carry no trip counter; a
+        # sharded backend's counters hold one value per shard)
+        full, steps, shard_trips = jax.device_get(
             (wave.res.score, wave.res.n_steps,
              getattr(wave.res, "n_ext_trips", None)))
         out = full[: len(wave.rows)]
-        steps = int(steps)
-        trips = None if trips is None else int(trips)
+        steps = int(np.sum(steps))
+        if shard_trips is not None:
+            shard_trips = np.ravel(shard_trips)
+        trips = None if shard_trips is None else int(shard_trips.sum())
         t2 = time.perf_counter()
         if not self._sync:       # sync mode billed the kernel at dispatch
             for st in (ticket.stats, self.stats):
@@ -558,6 +567,14 @@ class AlignmentSession:
             obs_metrics.counter("kernel_extend_trips_total",
                                 "extend trips of retired waves, summed "
                                 "over the kernel's grid blocks").inc(trips)
+            if shard_trips.size > 1:
+                n_max, n_mean = SHARD_COUNTERS
+                obs_metrics.counter(
+                    n_max, "extend trips of each retired wave's slowest "
+                    "shard").inc(int(shard_trips.max()))
+                obs_metrics.counter(
+                    n_mean, "extend trips of each retired wave, averaged "
+                    "over its shards").inc(float(shard_trips.mean()))
         if ticket._meet is not None:
             r = wave.res
             nr = len(wave.rows)

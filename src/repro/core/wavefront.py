@@ -128,13 +128,15 @@ class WFAResult(NamedTuple):
     i_hist: Optional[jax.Array]  # None for linear models (no I/D fronts)
     d_hist: Optional[jax.Array]
     # [] int32: score loop trips taken (telemetry); the Pallas kernel's
-    # are summed over its grid blocks, each running its own loop
+    # are summed over its grid blocks, each running its own loop, and
+    # under ``shardmap`` come back as one sum per shard ([shards])
     n_steps: jax.Array
     m_bt: Optional[jax.Array] = None  # [n_trace_words, B, K] packed 2-bit
     i_bt: Optional[jax.Array] = None  # provenance codes, or None (score mode
     d_bt: Optional[jax.Array] = None  # / linear models)
     # [] int32 extend trips summed over the Pallas kernel's grid blocks
-    # (telemetry); None for backends without a trip counter
+    # ([shards] under ``shardmap``; telemetry); None for backends without
+    # a trip counter
     n_ext_trips: Optional[jax.Array] = None
 
 
@@ -1133,55 +1135,13 @@ def wfa_bidir_meet(pattern, text, plen, tlen, starget, *, pen, s_max: int,
                            jnp.where(met, jst, -1), ja, jb, jk, jh, jsf)
 
 
-def wfa_trace_shardmap(pattern, text, plen, tlen, *, pen,
-                       s_max: int, k_max: int, mesh, axis_names=None,
-                       heur=None, band_cap=None):
-    """Per-shard packed-backtrace WFA under ``shard_map``.
-
-    The shardmap backend's CIGAR fallback: each shard runs the packed ring
-    solver to local termination (no collectives, per-shard early exit — same
-    discipline as :func:`wfa_scores_shardmap`) and the packed provenance
-    words come back sharded on the pair axis for host-side traceback.
-    Returns ``(score, m_bt, i_bt, d_bt)`` with ``i_bt = d_bt = None`` for
-    linear models.
-    """
-    from jax.sharding import PartitionSpec as P
-
-    model = scoring.as_model(pen)
-    names = tuple(axis_names if axis_names is not None else mesh.axis_names)
-    spec2 = P(names, None)
-    spec1 = P(names)
-    spec_bt = P(None, names, None)
-    affine = model.kind == "affine"
-
-    if affine:
-        def local(p, t, pl, tl):
-            r = wfa_scores_packed(p, t, pl, tl, pen=pen, s_max=s_max,
-                                  k_max=k_max, heur=heur, band_cap=band_cap)
-            return r.score, r.m_bt, r.i_bt, r.d_bt
-
-        out_specs = (spec1, spec_bt, spec_bt, spec_bt)
-    else:
-        def local(p, t, pl, tl):
-            r = wfa_scores_packed(p, t, pl, tl, pen=pen, s_max=s_max,
-                                  k_max=k_max, heur=heur, band_cap=band_cap)
-            return r.score, r.m_bt
-
-        out_specs = (spec1, spec_bt)
-
-    fn = jax.shard_map(local, mesh=mesh,
-                       in_specs=(spec2, spec2, spec1, spec1),
-                       out_specs=out_specs, check_vma=False)
-    out = fn(pattern, text, plen, tlen)
-    if affine:
-        return out
-    return out[0], out[1], None, None
-
-
 def wfa_scores_shardmap(pattern, text, plen, tlen, *, pen,
                         s_max: int, k_max: int, mesh, axis_names=None,
                         heur=None, band_cap=None):
     """PIM-faithful distributed WFA: per-shard termination via shard_map.
+
+    The ring solver per shard, kept for ``launch.lowering``'s dry-run
+    cells; the ``shardmap`` backend runs the Pallas kernel per shard.
 
     The pjit formulation's while-condition ``any(score < 0)`` spans the
     GLOBAL batch, so SPMD inserts a small all-reduce every score iteration

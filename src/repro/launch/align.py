@@ -48,7 +48,7 @@ from repro import obs
 from repro.configs import wfa_paper
 from repro.core import cigar as cigar_mod
 from repro.core import scoring
-from repro.core.backends import available_backends, get_backend
+from repro.core.backends import available_backends
 from repro.core.engine import AlignmentEngine
 from repro.core.gotoh import gotoh_score_vec, score_cigar
 from repro.core.session import run_streamed
@@ -190,13 +190,9 @@ def run(argv=None) -> Tuple[int, Optional[np.ndarray]]:
     log(f"[align] scoring: {pen} heuristic={heur}"
         + (" (approximate scores)" if not heur.exact else ""))
 
-    mesh = None
-    if get_backend(args.backend).needs_mesh:
-        from repro.launch.mesh import make_host_mesh
-        mesh = make_host_mesh()
     engine = AlignmentEngine(pen, backend=args.backend,
                              edit_frac=args.edit_frac, heuristic=heur,
-                             chunk_pairs=args.chunk_pairs, mesh=mesh,
+                             chunk_pairs=args.chunk_pairs,
                              bucket_by_length=not args.no_bucket,
                              adaptive=not args.no_adaptive,
                              trace_variant=args.trace)

@@ -24,7 +24,7 @@ import numpy as np
 
 from repro import obs
 from repro.core import scoring
-from repro.core.backends import available_backends, get_backend
+from repro.core.backends import available_backends
 from repro.core.engine import AlignmentEngine
 from repro.data.io import read_seqs
 from repro.launch.runtime import device_tag, enable_compile_cache
@@ -133,12 +133,8 @@ def main(argv=None):
            if args.penalties else scoring.as_model(None))
     heur = scoring.parse_heuristic(args.heuristic)
     read_len = int(np.median([len(r) for r in reads])) if reads else 100
-    mesh = None
-    if get_backend(args.backend).needs_mesh:
-        from repro.launch.mesh import make_host_mesh
-        mesh = make_host_mesh()
     engine = AlignmentEngine(
-        pen, backend=args.backend, heuristic=heur, mesh=mesh,
+        pen, backend=args.backend, heuristic=heur,
         edit_frac=suggested_edit_frac(pen, args.edit_frac, read_len))
     mapper = ReadMapper(index, engine, top_n=args.top_n,
                         edit_frac=args.edit_frac, read_len=read_len,

@@ -6,7 +6,9 @@ chip of a described ``v5e:2x2`` topology, and checks the Mosaic kernel is
 in the compiled program.  Each runs at every packed-fetch width the
 session can pick (``char_bits`` 2, 8 and 32: 16, 4 and 1 characters per
 extend trip), since each lowers its own field count and shifts.  Nothing runs; the compiler refuses here what it
-would refuse on the chip (VMEM, unsupported primitives).
+would refuse on the chip (VMEM, unsupported primitives).  The ``shardmap``
+executables compile over the whole described 2x2 mesh, at the four-chip
+cell's 8,192-row waves.
 """
 import functools
 
@@ -14,8 +16,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
+    SingleDeviceSharding
 
+from repro.core.backends import get_backend
 from repro.core.engine import AlignmentEngine
 from repro.core.scoring import AdaptiveBand, Edit, GapAffine, GapLinear
 from repro.kernels.wfa import ops as kops
@@ -38,6 +42,11 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(np.array(topo.devices), ("pairs",))
 
 
 @pytest.fixture
@@ -111,3 +120,33 @@ def test_vmem_overflow_is_refused_by_name(one_chip, no_persistent_cache,
     fn = kops.wfa_align_trace if trace else kops.wfa_align
     with pytest.raises(ValueError, match="scoped VMEM"):
         _compile(one_chip, fn, pen=AFFINE, s_max=s_max, k_max=k_max)
+
+
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "all-to-all")
+
+
+@pytest.mark.parametrize("variant", ["fn", "trace_variant"],
+                         ids=["score", "trace"])
+def test_sharded_kernel_compiles_without_collectives(
+        four_chips, no_persistent_cache, monkeypatch, variant):
+    """The ``shardmap`` backend at the four-chip cell's shapes (8,192
+    rows, E = 2% bounds, 16 bases per trip) over the four chips: the
+    Pallas kernel on every shard and no collective between them."""
+    # the backend asks the platform whether to interpret; here it is the
+    # CPU, but the program is compiled for the described chips
+    monkeypatch.setattr(kops, "default_interpret", lambda: False)
+    s_max, k_max = _bounds(AFFINE, 0.02, False)
+    rows = NamedSharding(four_chips, PartitionSpec("pairs"))
+    cols = NamedSharding(four_chips, PartitionSpec("pairs", None))
+    fn = getattr(get_backend("shardmap"), variant)
+    call = jax.jit(functools.partial(fn, pen=AFFINE, s_max=s_max,
+                                     k_max=k_max, mesh=four_chips,
+                                     char_bits=2))
+    n = 4 * PAIRS
+    spec = lambda shape, sh: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                                  sharding=sh)
+    hlo = call.lower(spec((n, WIDTH), cols), spec((n, WIDTH), cols),
+                     spec((n,), rows), spec((n,), rows)).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert [op for op in COLLECTIVES if op in hlo] == []

@@ -28,6 +28,8 @@ PAIRS = {"score": np.zeros(64, np.int32)}
 COUNTER_READERS = ["score_steps_per_pair", "extend_trips_per_pair",
                    "compile_s"]
 SPAN_READERS = ["dispatch_us_per_pair", "retire_us_per_pair"]
+# readers of names a program may predate while it declares others
+MESH_READERS = ["shard_trip_skew", "put_us_per_pair"]
 
 
 @pytest.fixture
@@ -77,7 +79,8 @@ def test_counter_readers_refuse_a_renamed_counter(registry, monkeypatch,
         harness.metric_reader(name)({"pairs": PAIRS})
 
 
-@pytest.mark.parametrize("name", COUNTER_READERS + SPAN_READERS)
+@pytest.mark.parametrize("name", COUNTER_READERS + SPAN_READERS
+                         + MESH_READERS)
 def test_readers_are_silent_on_a_program_that_predates_them(
         registry, monkeypatch, name):
     monkeypatch.delattr(session, "WAVE_COUNTERS")
@@ -100,7 +103,12 @@ def test_counter_readers_read_a_kernel_session(registry):
     assert read("extend_trips_per_pair")(ctx) == pytest.approx(
         res.stats.n_ext_trips / 16)
     assert read("compile_s")(ctx) > 0
-    assert set(session.WAVE_COUNTERS) <= set(registry._metrics)
+    # one device: every counter but the per-shard ones, which waves split
+    # over several shards write
+    assert (set(session.WAVE_COUNTERS) - set(registry._metrics)
+            == set(session.SHARD_COUNTERS))
+    with pytest.raises(RuntimeError, match="kernel_shard_trips"):
+        read("shard_trip_skew")(ctx)
 
 
 # -- span readers -------------------------------------------------------------
@@ -152,3 +160,54 @@ def test_span_readers_refuse_a_renamed_span(monkeypatch, name):
     ctx = {"pairs": PAIRS, "reduction": tracered.Reduction(_trace(HOST))}
     with pytest.raises(RuntimeError):
         harness.metric_reader(name)(ctx)
+
+
+# -- readers of the four-chip deployment --------------------------------------
+
+def test_shard_skew_reader_compares_the_slowest_shard_with_the_mean(
+        registry):
+    _count(registry, kernel_shard_trips_max_total=130,
+           kernel_shard_trips_mean_total=104)
+    read = harness.metric_reader("shard_trip_skew")
+    assert read({"pairs": PAIRS}) == pytest.approx(25.0)
+    assert read({"pairs": {"score": np.zeros(0)}}) is None
+
+
+def test_shard_skew_reader_refuses_a_declared_counter_left_empty(registry):
+    _count(registry, kernel_shard_trips_max_total=130)
+    with pytest.raises(RuntimeError, match="kernel_shard_trips_mean"):
+        harness.metric_reader("shard_trip_skew")({"pairs": PAIRS})
+
+
+def test_put_reader_sums_its_spans_inside_the_window():
+    host = HOST + [["wave.put", 4 * MS, 2 * MS],    # 1 ms inside the window
+                   ["wave.put", 7.2 * MS, 0.2 * MS],
+                   ["wave.put", 61.1 * MS, 0.3 * MS]]
+    ctx = {"pairs": PAIRS, "reduction": tracered.Reduction(_trace(host))}
+    read = harness.metric_reader("put_us_per_pair")
+    assert read(ctx) == pytest.approx(1.5e3 / 64)
+    # the dispatch reader still sums dispatches only, not the copy inside
+    assert harness.metric_reader("dispatch_us_per_pair")(ctx) == (
+        pytest.approx(6.5e3 / 64))
+
+
+def test_put_reader_refuses_a_declared_span_left_out():
+    ctx = {"pairs": PAIRS, "reduction": tracered.Reduction(_trace(HOST))}
+    with pytest.raises(RuntimeError, match="wave.put"):
+        harness.metric_reader("put_us_per_pair")(ctx)
+
+
+@pytest.mark.parametrize("name", MESH_READERS)
+def test_mesh_readers_are_silent_where_the_names_are_not_declared(
+        registry, monkeypatch, name):
+    """A program that declares its other spans and counters but predates
+    these names (the parent of the four-chip cell) gives no reading."""
+    _count(registry, kernel_shard_trips_max_total=130,
+           kernel_shard_trips_mean_total=104)
+    monkeypatch.setattr(session, "WAVE_COUNTERS", tuple(
+        n for n in session.WAVE_COUNTERS if n not in session.SHARD_COUNTERS))
+    monkeypatch.setattr(session, "WAVE_SPANS", tuple(
+        n for n in session.WAVE_SPANS if n != "wave.put"))
+    host = HOST + [["wave.put", 7.2 * MS, 0.2 * MS]]
+    ctx = {"pairs": PAIRS, "reduction": tracered.Reduction(_trace(host))}
+    assert harness.metric_reader(name)(ctx) is None
