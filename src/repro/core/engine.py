@@ -345,7 +345,8 @@ class EngineStats:
 class EngineResult:
     scores: np.ndarray                      # [B] int32; -1 = exceeded s_max
     cigars: Optional[List[np.ndarray]]      # per-pair op arrays, or None
-    n_steps: int                            # score-loop trips (telemetry)
+    n_steps: int                            # score-loop steps of the
+                                            # gcd-reduced model (telemetry)
     s_max: int                              # largest bound used
     k_max: int
     stats: EngineStats = dataclasses.field(default_factory=EngineStats)
@@ -384,6 +385,18 @@ class _Executable:
     streaming session: it honors the backend's ``dispatch`` hook and is
     *non-blocking* — the returned ``WFAResult`` holds in-flight device
     arrays (JAX async dispatch), so callers choose when to synchronize.
+
+    The backend runs ``run_pen``, the caller's model divided by the gcd
+    ``score_scale`` of its penalties (:meth:`~repro.core.scoring.
+    PenaltyModel.reduced`), with the bound ``s_max // score_scale``: a
+    pair of cost S <= ``s_max`` resolves to S / ``score_scale``, and one
+    above ``s_max`` stays unresolved, so the same pairs come back ``-1``
+    as under the caller's model.  Its result is in the reduced units: the
+    session multiplies scores back and decodes traces against
+    ``run_pen``.  ``s_max`` and ``k_max`` stay the caller's.  Breakpoint
+    waves (``"bidir_meet"``), boundary-state children and bounds below
+    the gcd run the caller's model (``score_scale`` 1): their known costs
+    and caps are the BiWFA recursion's, in the caller's units.
     """
 
     def __init__(self, spec: BackendSpec, pen, s_max: int,
@@ -399,6 +412,14 @@ class _Executable:
         pen = scoring.as_model(pen)
         heur = scoring.as_heuristic(heur)
         states = tuple(states)
+        run_pen, scale = pen, 1
+        if output != "bidir_meet" and states == ("M", "M"):
+            run_pen, scale = pen.reduced()
+            if s_max < scale:
+                run_pen, scale = pen, 1
+        self.run_pen = run_pen
+        self.score_scale = scale
+        run_s_max = s_max // scale
         if output == "bidir_meet":
             # the meet-in-the-middle breakpoint solver: backends may ship a
             # fused meet variant (the kernel runs both fronts' rings in
@@ -451,8 +472,8 @@ class _Executable:
 
         def _run(*arrays):
             traces[0] += 1            # trace-time side effect only
-            return backend_fn(*arrays, pen=pen,
-                              s_max=s_max, k_max=k_max, **extra)
+            return backend_fn(*arrays, pen=run_pen,
+                              s_max=run_s_max, k_max=k_max, **extra)
 
         # Donation is a no-op (with a warning) on CPU; only apply it where
         # XLA can actually alias the buffers.
@@ -495,7 +516,10 @@ class AlignmentEngine:
         legacy gap-affine :class:`Penalties` triple (normalized to
         ``GapAffine``).  Every ``align``/``submit`` can override per call
         via ``penalties=``; linear models run the cheaper one-matrix
-        recurrence end to end.
+        recurrence end to end.  Executables run the model divided by the
+        gcd of its penalties (4/6/2 runs as 2/3/1, with half the score
+        steps); scores, bounds, stats and CIGARs come back in this
+        model's units.
     backend : registry name (``available_backends()``); plug-ins welcome.
     edit_frac : the paper's E — optimistic score budget for pass 1.  ``None``
         sizes buffers for the exact worst case up front (single pass).

@@ -26,6 +26,13 @@ Penalty models (match always costs 0):
   recurrence with every delta equal to 1, the cheapest variant (window of
   2 wavefronts, score == edit distance).
 
+The engine's executables run each model divided by the gcd ``g`` of its
+penalties (:meth:`PenaltyModel.reduced`): every alignment costs exactly
+``g`` times its reduced cost, so both models have the same optimal
+alignments, and the wavefronts at scores that are not multiples of ``g``
+(every odd score of the paper's 4/6/2) are empty steps the reduced model
+never runs.  Scores and CIGARs come back in the caller's units.
+
 Wavefront heuristics (the follow-up paper's WFA-adaptive story):
 
 * :class:`NoHeuristic` — exact scores, the default.
@@ -48,7 +55,8 @@ result produced under a non-exact heuristic is flagged
 from __future__ import annotations
 
 import dataclasses
-from typing import Union
+import math
+from typing import Tuple, Union
 
 from repro.core import penalties as penalties_mod
 from repro.core.penalties import Penalties
@@ -126,6 +134,13 @@ class PenaltyModel:
         """Exact worst case: all-mismatch diagonal plus one closing gap."""
         return self.x * min(plen, tlen) + self.gap_cost(abs(tlen - plen))
 
+    def reduced(self) -> Tuple["PenaltyModel", int]:
+        """-> (model, g): the same recurrence with every penalty divided
+        by their gcd ``g``.  Each alignment's cost here is ``g`` times its
+        reduced cost.  Models with no common factor (and :class:`Edit`)
+        return themselves and 1."""
+        return self, 1
+
 
 @dataclasses.dataclass(frozen=True)
 class GapAffine(PenaltyModel):
@@ -145,6 +160,13 @@ class GapAffine(PenaltyModel):
     @property
     def kind(self) -> str:
         return "affine"
+
+    def reduced(self) -> Tuple["GapAffine", int]:
+        g = math.gcd(self.mismatch, self.gap_open, self.gap_extend)
+        if g == 1:
+            return self, 1
+        return GapAffine(self.mismatch // g, self.gap_open // g,
+                         self.gap_extend // g), g
 
     @property
     def x(self) -> int:
@@ -173,6 +195,12 @@ class GapLinear(PenaltyModel):
     @property
     def kind(self) -> str:
         return "linear"
+
+    def reduced(self) -> Tuple["GapLinear", int]:
+        g = math.gcd(self.mismatch, self.gap_extend)
+        if g == 1:
+            return self, 1
+        return GapLinear(self.mismatch // g, self.gap_extend // g), g
 
     @property
     def x(self) -> int:

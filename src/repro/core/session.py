@@ -65,9 +65,9 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import record as obs_record
 from repro.obs import trace as obs_trace
 
-__all__ = ["AlignmentSession", "FETCH_PAIRS", "SHARD_COUNTERS",
-           "SessionStats", "Ticket", "WAVE_COUNTERS", "WAVE_SPANS",
-           "run_streamed"]
+__all__ = ["AlignmentSession", "FETCH_PAIRS", "GCD_PAIRS",
+           "SHARD_COUNTERS", "SessionStats", "Ticket", "WAVE_COUNTERS",
+           "WAVE_SPANS", "run_streamed"]
 
 # The spans each wave opens (pack, then dispatch with the host-to-device
 # copy inside it, and a compile too on a cache miss; wait, gather and, for
@@ -87,6 +87,9 @@ WAVE_COUNTERS = ("kernel_pairs_total", "kernel_score_steps_total",
 # for other byte codes, 1 for wider codes), beside kernel_pairs_total.
 FETCH_PAIRS = {c: f"kernel_pairs_chars_per_trip_{c}_total"
                for c in (16, 4, 1)}
+# Pairs retired from waves whose executable ran the penalties divided by
+# their gcd (``_Executable.score_scale`` above 1).
+GCD_PAIRS = "engine_gcd_pairs_total"
 
 
 @dataclasses.dataclass
@@ -182,6 +185,8 @@ class _Wave:
     pc: Optional[np.ndarray] = None   # padded codes, kept only for CIGAR
     tc: Optional[np.ndarray] = None   # waves (packed-backtrace replay)
     chars_per_trip: Optional[int] = None   # the executable's fetch width
+    run_pen: object = None      # the model the executable ran, and the
+    score_scale: int = 1        # factor from its scores to the ticket's
 
 
 class AlignmentSession:
@@ -489,7 +494,9 @@ class AlignmentSession:
         self._inflight.append(_Wave(ticket, rows, res, plc, tlc, k_max,
                                     recovery, pc=pc if keep else None,
                                     tc=tc if keep else None,
-                                    chars_per_trip=exe.chars_per_trip))
+                                    chars_per_trip=exe.chars_per_trip,
+                                    run_pen=exe.run_pen,
+                                    score_scale=exe.score_scale))
         self.stats.n_waves += 1
         self.stats.peak_inflight = max(self.stats.peak_inflight,
                                        len(self._inflight))
@@ -536,6 +543,13 @@ class AlignmentSession:
             (wave.res.score, wave.res.n_steps,
              getattr(wave.res, "n_ext_trips", None)))
         out = full[: len(wave.rows)]
+        if wave.score_scale > 1:
+            # back from the gcd-reduced model to the ticket's units
+            out = np.where(out >= 0, out * wave.score_scale, out)
+            obs_metrics.counter(GCD_PAIRS,
+                                "real pair rows retired from waves run on "
+                                "the penalties divided by their gcd"
+                                ).inc(len(wave.rows))
         steps = int(np.sum(steps))
         if shard_trips is not None:
             shard_trips = np.ravel(shard_trips)
@@ -594,7 +608,7 @@ class AlignmentSession:
                     tsp.flow_step(fid)
                 t3 = time.perf_counter()
                 ops = cigar_mod.traceback_result(
-                    wave.res, ticket.pen, pattern=wave.pc, text=wave.tc,
+                    wave.res, wave.run_pen, pattern=wave.pc, text=wave.tc,
                     plen=wave.plc, tlen=wave.tlc, k_max=wave.k_max,
                     begin_state=ticket.states[0],
                     end_state=ticket.states[1])

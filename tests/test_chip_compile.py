@@ -150,3 +150,35 @@ def test_sharded_kernel_compiles_without_collectives(
                      spec((n,), rows), spec((n,), rows)).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert [op for op in COLLECTIVES if op in hlo] == []
+
+
+@pytest.mark.parametrize("backend,edit_frac,output", [
+    ("kernel", 0.04, "score"), ("kernel", 0.04, "cigar"),
+    ("shardmap", 0.02, "score")],
+    ids=["one-chip-e4-score", "one-chip-e4-cigar", "four-chips-e2-score"])
+def test_engine_executable_compiles_on_the_reduced_model(
+        topo, no_persistent_cache, monkeypatch, backend, edit_frac, output):
+    """The executable the engine builds for the benchmark's cells runs
+    4/6/2 as 2/3/1 under ``s_max // 2``; that program compiles, with the
+    Pallas kernel in it, on one chip and over the 2x2 mesh."""
+    monkeypatch.setattr(kops, "default_interpret", lambda: False)
+    if backend == "shardmap":
+        mesh = Mesh(np.array(topo.devices), ("pairs",))
+        rows = NamedSharding(mesh, PartitionSpec("pairs"))
+        cols = NamedSharding(mesh, PartitionSpec("pairs", None))
+        n = 4 * PAIRS
+    else:
+        mesh, n = None, PAIRS
+        rows = cols = SingleDeviceSharding(topo.devices[0])
+    eng = AlignmentEngine(AFFINE, backend=backend, edit_frac=edit_frac,
+                          mesh=mesh)
+    s_max, k_max = _bounds(AFFINE, edit_frac, False)
+    exe, _ = eng._executable_for((n, WIDTH), (n, WIDTH), s_max, k_max,
+                                 output, char_bits=2)
+    assert (exe.run_pen, exe.score_scale) == (GapAffine(2, 3, 1), 2)
+    spec = lambda shape, sh: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                                  sharding=sh)
+    hlo = exe.fn.lower(spec((n, WIDTH), cols), spec((n, WIDTH), cols),
+                       spec((n,), rows), spec((n,), rows)
+                       ).compile().as_text()
+    assert "tpu_custom_call" in hlo

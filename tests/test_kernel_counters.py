@@ -16,6 +16,7 @@ from repro.core.backends import get_backend
 from repro.core.engine import AlignmentEngine, fetch_char_bits, pack_batch
 from repro.core.gotoh import gotoh_score_vec
 from repro.core.penalties import DEFAULT
+from repro.core.scoring import as_model
 from repro.kernels.wfa import ops as kops
 from repro.obs.metrics import REGISTRY
 from repro.serve import ServeLoop
@@ -192,7 +193,9 @@ def _fetch_pairs(chars_per_trip):
 def test_kernel_backend_reports_its_real_counts(output):
     """``n_steps`` is the kernel's summed block steps (not ``s_max``), and
     ``n_ext_trips`` its summed trips, through the backend and the
-    engine, at the fetch width the engine chose for these codes."""
+    engine, at the fetch width the engine chose for these codes.  The
+    engine runs 4/6/2 divided by its gcd, as 2/3/1 under ``S_MAX // 2``,
+    so its counts are the plain loop's under that model."""
     pats, txts = _pairs(CASES["mixed_two_blocks"] + ["mismatch"] * 4,
                         seed=3)
     P, plen = pack_batch(pats)
@@ -207,13 +210,18 @@ def test_kernel_backend_reports_its_real_counts(output):
     assert int(res.n_steps) == want_steps
     assert int(res.n_ext_trips) == want_trips
 
+    run_pen, g = as_model(DEFAULT).reduced()
+    assert g == 2
+    _, run_steps, run_trips = _reference(pats, txts, pen=run_pen,
+                                         s_max=S_MAX // g,
+                                         chars_per_trip=chars)
     eng = AlignmentEngine(DEFAULT, backend="kernel", s_max=S_MAX,
                           k_max=K_MAX)
     before = _fetch_pairs(chars)
     er = eng.align(pats, txts, output=output)
     assert (er.scores >= 0).all()
-    assert er.n_steps == want_steps != er.s_max
-    assert er.stats.n_ext_trips == want_trips
+    assert er.n_steps == run_steps != er.s_max
+    assert er.stats.n_ext_trips == run_trips
     assert _fetch_pairs(chars) - before == len(pats)
 
 
