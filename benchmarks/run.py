@@ -108,12 +108,10 @@ def main(argv=None) -> int:
                            pairs=min(max(args.pairs // 64, 8), 32))))
     if want is None or "kernelgap" in want:
         from benchmarks import kernelgap
-        # interpret-mode kernel runs: keep the batch modest and skip the
-        # (very slow) informational one-hot row in sweeps
+        # interpret-mode kernel runs: keep the batch modest
         suites.append(("kernelgap",
                        lambda: kernelgap.run(
-                           pairs=min(max(args.pairs // 8, 64), 256),
-                           onehot=False)))
+                           pairs=min(max(args.pairs // 8, 64), 256))))
     if want is None or "wfa_ops" in want:
         from benchmarks import wfa_ops
         suites.append(("wfa_ops", wfa_ops.run))

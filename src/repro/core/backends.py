@@ -40,6 +40,10 @@ Every backend serves two *output modes* (the engine's
   I/D planes are ``None`` for linear models).  ``supports_cigar`` is
   simply "has a trace variant"; score-only plug-ins may omit it.
 
+The breakpoint waves of ``trace_variant="bidir"`` belong to no backend:
+the engine runs the jnp meet solver ``wf.wfa_bidir_meet`` for them on
+every backend and platform.
+
 Backends that shard over a device mesh set ``needs_mesh`` and receive the
 engine's ``mesh`` as a keyword (an engine given none spans every local
 device).  Two further hooks tune how the engine
@@ -63,8 +67,9 @@ heuristic):
                    history (the memory-hungry oracle path)
 * ``"ring"``     — rolling-window pure-jnp WFA; trace variant records the
                    packed backtrace alongside the rings
-* ``"kernel"``   — the Pallas TPU kernel (interpret=True on CPU); trace
-                   variant OR-accumulates packed words in VMEM
+* ``"kernel"``   — the Pallas TPU kernel (interpret=True on CPU, with
+                   the chip's fetch); trace variant OR-accumulates packed
+                   words in VMEM
 * ``"shardmap"`` — the ``kernel`` backend per shard inside ``shard_map``
                    over every device of the mesh (per-shard termination,
                    zero collectives — the paper's "no inter-DPU
@@ -109,7 +114,6 @@ class BackendSpec:
     name: str
     fn: Callable[..., wf.WFAResult]
     trace_variant: Optional[Callable[..., wf.WFAResult]] = None
-    meet_variant: Optional[Callable[..., "wf.BidirMeetResult"]] = None
     needs_mesh: bool = False
     donate_args: Tuple[int, ...] = ()
     dispatch: Optional[Callable[..., wf.WFAResult]] = None
@@ -131,8 +135,8 @@ class BackendSpec:
     def callables(self) -> Tuple[Callable, ...]:
         """Every non-None solver callable this backend exposes (used by the
         engine to validate ``backend_opts`` keys up front)."""
-        return tuple(f for f in (self.fn, self.trace_variant,
-                                 self.meet_variant) if f is not None)
+        return tuple(f for f in (self.fn, self.trace_variant)
+                     if f is not None)
 
     def accepts_states(self) -> bool:
         """Whether the trace variant takes ``begin_state``/``end_state``
@@ -168,7 +172,6 @@ _REGISTRY: Dict[str, BackendSpec] = {}
 
 def register_backend(name: str, fn: Optional[Callable] = None, *,
                      trace_variant: Optional[Callable] = None,
-                     meet_variant: Optional[Callable] = None,
                      supports_cigar: bool = False,
                      needs_mesh: bool = False,
                      donate_args: Tuple[int, ...] = (),
@@ -181,10 +184,7 @@ def register_backend(name: str, fn: Optional[Callable] = None, *,
     for swapping in tuned variants).  ``models`` declares the penalty-model
     recurrence kinds the backend serves (plug-ins default to affine-only;
     pass ``models=("affine", "linear")`` when the backend handles linear
-    models too).  ``meet_variant`` optionally replaces the shared jnp
-    BiWFA meet solver (``wf.wfa_bidir_meet`` — same signature and
-    ``BidirMeetResult`` contract) for ``trace_variant="bidir"`` meet
-    waves.  ``supports_cigar=True`` is the deprecated pre-output-mode
+    models too).  ``supports_cigar=True`` is the deprecated pre-output-mode
     spelling for backends whose ``fn`` itself returns a traceback-capable
     ``WFAResult`` (full history, like the old ``ref``): it makes ``fn``
     double as the trace variant.
@@ -195,7 +195,6 @@ def register_backend(name: str, fn: Optional[Callable] = None, *,
             tv = f
         _REGISTRY[name] = BackendSpec(name=name, fn=f,
                                       trace_variant=tv,
-                                      meet_variant=meet_variant,
                                       needs_mesh=needs_mesh,
                                       donate_args=tuple(donate_args),
                                       dispatch=dispatch,
@@ -275,40 +274,27 @@ def _ring_backend(pattern, text, plen, tlen, *, pen, s_max, k_max, heur=None,
 
 
 def _kernel_trace(pattern, text, plen, tlen, *, pen, s_max, k_max,
-                  heur=None, block_pairs=None, gather=None, char_bits=32,
-                  band_cap=None):
+                  heur=None, block_pairs=None, char_bits=32, band_cap=None):
     from repro.kernels.wfa import ops as kops  # lazy: pallas import is heavy
     score, m_bt, i_bt, d_bt, steps, trips = kops.wfa_align_trace(
         pattern, text, plen, tlen, pen=pen, s_max=s_max, k_max=k_max,
-        heur=heur, block_pairs=block_pairs, gather=gather,
-        char_bits=char_bits, band_cap=band_cap, counts=True)
+        heur=heur, block_pairs=block_pairs, char_bits=char_bits,
+        band_cap=band_cap, counts=True)
     return wf.WFAResult(score, None, None, None, steps, m_bt, i_bt, d_bt,
                         n_ext_trips=trips)
 
 
-def _kernel_meet(pattern, text, plen, tlen, starget, *, pen, s_max, k_max,
-                 heur=None, begin_state="M", end_state="M",
-                 block_pairs=None):
-    from repro.kernels.wfa import ops as kops  # lazy: pallas import is heavy
-    return kops.wfa_bidir_meet_kernel(
-        pattern, text, plen, tlen, starget, pen=pen, s_max=s_max,
-        k_max=k_max, heur=heur, begin_state=begin_state,
-        end_state=end_state, block_pairs=block_pairs)
-
-
 @register_backend("kernel", donate_args=(2, 3), trace_variant=_kernel_trace,
-                  meet_variant=_kernel_meet,
                   models=ALL_MODELS,
                   doc="Pallas TPU kernel (interpret on CPU); packed "
-                      "backtrace in VMEM; fused in-grid BiWFA meet")
+                      "backtrace in VMEM")
 def _kernel_backend(pattern, text, plen, tlen, *, pen, s_max, k_max,
-                    heur=None, block_pairs=None, gather=None, char_bits=32,
-                    band_cap=None):
+                    heur=None, block_pairs=None, char_bits=32, band_cap=None):
     from repro.kernels.wfa import ops as kops  # lazy: pallas import is heavy
     score, steps, trips = kops.wfa_align(
         pattern, text, plen, tlen, pen=pen, s_max=s_max, k_max=k_max,
-        heur=heur, block_pairs=block_pairs, gather=gather,
-        char_bits=char_bits, band_cap=band_cap, counts=True)
+        heur=heur, block_pairs=block_pairs, char_bits=char_bits,
+        band_cap=band_cap, counts=True)
     return wf.WFAResult(score, None, None, None, steps, n_ext_trips=trips)
 
 
@@ -341,14 +327,13 @@ def _per_shard(kernel_fn, n_bt: int, mesh, pattern, text, plen, tlen, **kw):
 
 
 def _shardmap_trace(pattern, text, plen, tlen, *, pen, s_max, k_max, mesh,
-                    heur=None, block_pairs=None, gather=None, char_bits=32,
-                    band_cap=None):
+                    heur=None, block_pairs=None, char_bits=32, band_cap=None):
     # linear models record one M plane, affine ones M, I and D
     n_bt = 3 if scoring.as_model(pen).kind == "affine" else 1
     return _per_shard(_kernel_trace, n_bt, mesh, pattern, text, plen, tlen,
                       pen=pen, s_max=s_max, k_max=k_max, heur=heur,
-                      block_pairs=block_pairs, gather=gather,
-                      char_bits=char_bits, band_cap=band_cap)
+                      block_pairs=block_pairs, char_bits=char_bits,
+                      band_cap=band_cap)
 
 
 @register_backend("shardmap", needs_mesh=True, trace_variant=_shardmap_trace,
@@ -357,9 +342,9 @@ def _shardmap_trace(pattern, text, plen, tlen, *, pen, s_max, k_max, mesh,
                       "termination, zero collectives; per-shard loop "
                       "counters and packed backtrace")
 def _shardmap_backend(pattern, text, plen, tlen, *, pen, s_max, k_max, mesh,
-                      heur=None, block_pairs=None, gather=None, char_bits=32,
+                      heur=None, block_pairs=None, char_bits=32,
                       band_cap=None):
     return _per_shard(_kernel_backend, 0, mesh, pattern, text, plen, tlen,
                       pen=pen, s_max=s_max, k_max=k_max, heur=heur,
-                      block_pairs=block_pairs, gather=gather,
-                      char_bits=char_bits, band_cap=band_cap)
+                      block_pairs=block_pairs, char_bits=char_bits,
+                      band_cap=band_cap)

@@ -421,15 +421,9 @@ class _Executable:
         self.score_scale = scale
         run_s_max = s_max // scale
         if output == "bidir_meet":
-            # the meet-in-the-middle breakpoint solver: backends may ship a
-            # fused meet variant (the kernel runs both fronts' rings in
-            # VMEM with per-block early exit); otherwise the shared jnp
-            # solver serves every backend.  The fused meet kernel's
-            # per-pair gathers do not lower for the TPU, so there the jnp
-            # solver serves every backend too.
-            fused = (spec.meet_variant is not None
-                     and jax.default_backend() != "tpu")
-            backend_fn = spec.meet_variant if fused else wf.wfa_bidir_meet
+            # the meet-in-the-middle breakpoint solver: the jnp solver
+            # serves every backend
+            backend_fn = wf.wfa_bidir_meet
             self._dispatch = None
             extra = {}
         else:
@@ -441,7 +435,7 @@ class _Executable:
         # alignment has no pruning radius, so "auto" stays full-width).
         # Each opt is then threaded only into callables whose signature
         # takes it — the stateful-children ring substitution and the meet
-        # path keep working with kernel-only knobs configured.
+        # solver keep working with kernel-only knobs configured.
         opts = dict(opts)
         if opts.get("band_cap") == "auto":
             opts["band_cap"] = (None if heur.exact
@@ -547,10 +541,11 @@ class AlignmentEngine:
         ``band_cap`` (compacting-band ring width on ring/kernel/shardmap;
         ``"auto"`` derives it from the active heuristic's pruning radius
         via ``heur.band_cap`` — exact alignment stays full-width), plus
-        ``block_pairs`` / ``gather`` on the kernel backend.  Unknown keys
-        raise ``ValueError`` here, not at align time, and so does
-        ``char_bits``: the kernel's fetch width follows each ticket's codes
-        (:func:`fetch_char_bits`).
+        ``block_pairs`` on ``kernel`` / ``shardmap``.  Unknown keys raise
+        ``ValueError`` here, not at align time, and so does ``char_bits``:
+        the kernel's fetch width follows each ticket's codes
+        (:func:`fetch_char_bits`).  The kernel has one fetch and the
+        BiWFA meet waves one solver, so neither is an option.
     """
 
     def __init__(self, pen=DEFAULT, *, backend: str = "ring",

@@ -268,28 +268,6 @@ def _band_recenter(valid, prev_off, Kc, K):
     return jnp.where(hi >= lo, off, prev_off)
 
 
-def _band_read(ring, off_hist, s, delta, off, W):
-    """Ring row at score ``s - delta`` realigned to window offset ``off``."""
-    row = lax.rem(jnp.maximum(s - delta, 0), W)
-    r = lax.dynamic_index_in_dim(ring, row, keepdims=False)        # [B, Kc]
-    roff = lax.dynamic_index_in_dim(off_hist, row, keepdims=False)  # [B]
-    Kc = r.shape[-1]
-    idx = jnp.arange(Kc, dtype=jnp.int32)[None, :] + (off - roff)[:, None]
-    ok = (idx >= 0) & (idx < Kc) & (s >= delta)
-    return jnp.where(ok, jnp.take_along_axis(r, jnp.clip(idx, 0, Kc - 1),
-                                             axis=1), NEG)
-
-
-def _band_reached(M, plen, tlen, k_max, off):
-    """[B] bool: target diagonal reached, window-offset-aware."""
-    k_final = tlen - plen + k_max - off            # compact index
-    Kc = M.shape[-1]
-    in_band = (k_final >= 0) & (k_final < Kc)
-    idx = jnp.clip(k_final, 0, Kc - 1)
-    val = jnp.take_along_axis(M, idx[:, None], axis=1)[:, 0]
-    return in_band & (val >= tlen) & (val > _VALID_THRESH)
-
-
 def _band_scatter(code, off, K):
     """Spread a compact [B, Kc] code plane to absolute width [B, K]."""
     Kc = code.shape[-1]
@@ -297,146 +275,6 @@ def _band_scatter(code, off, K):
     ok = (idx >= 0) & (idx < Kc)
     return jnp.where(ok, jnp.take_along_axis(code, jnp.clip(idx, 0, Kc - 1),
                                              axis=1), 0)
-
-
-def _scores_band(pattern, text, plen, tlen, model, heur, s_max, k_max, Kc,
-                 packed, begin_state, end_state):
-    """Compacting-band ring solver (score-only or packed-backtrace).
-
-    Shared implementation behind ``wfa_scores(..., band_cap=)`` and
-    ``wfa_scores_packed(..., band_cap=)``; see the block comment above for
-    the window discipline.  Backtrace planes stay full width so traceback
-    is oblivious to the band."""
-    B = pattern.shape[0]
-    K = 2 * k_max + 1
-    W = model.window
-    affine = model.kind == "affine"
-
-    taint = (plen.reshape(-1)[0] * 0).astype(jnp.int32)
-    off0s = min(max(k_max - Kc // 2, 0), K - Kc)
-    j0 = k_max - off0s                              # seed lane, in [0, Kc)
-
-    def ks_of(off):
-        return off[:, None] + jnp.arange(Kc, dtype=jnp.int32)[None, :] - k_max
-
-    off0 = jnp.full((B,), off0s, jnp.int32) + taint
-    seed0 = jnp.full((B, Kc), NEG, jnp.int32).at[:, j0].set(0)
-    M0 = _extend(seed0, pattern, text, plen, tlen, ks_of(off0))
-
-    m_ring = (jnp.full((W, B, Kc), NEG, jnp.int32) + taint).at[0].set(M0)
-    off_hist = jnp.full((W, B), off0s, jnp.int32) + taint
-    negBK = jnp.full((B, Kc), NEG, jnp.int32)
-    I0 = seed0 if (affine and begin_state == "I") else negBK
-    D0 = seed0 if (affine and begin_state == "D") else negBK
-    if affine:
-        i_ring = (jnp.full((W, B, Kc), NEG, jnp.int32) + taint).at[0].set(I0)
-        d_ring = (jnp.full((W, B, Kc), NEG, jnp.int32) + taint).at[0].set(D0)
-
-    def end_front(M, I, D):
-        return {"M": M, "I": I, "D": D}[end_state]
-
-    front0 = M0 if not affine else end_front(M0, I0, D0)
-    score0 = _band_reached(front0, plen, tlen, k_max, off0)
-    score0 = jnp.where(score0, 0, -1)
-
-    NW = n_trace_words(s_max)
-    if packed:
-        m_bt = jnp.zeros((NW, B, K), jnp.int32) + taint
-        if affine:
-            i_bt = jnp.zeros((NW, B, K), jnp.int32) + taint
-            d_bt = jnp.zeros((NW, B, K), jnp.int32) + taint
-
-    def pack(bt, s, code, off):
-        w = s // TRACE_CELLS_PER_WORD
-        sh = 2 * lax.rem(s, TRACE_CELLS_PER_WORD)
-        word = lax.dynamic_index_in_dim(bt, w, keepdims=False)
-        full = _band_scatter(code, off, K)
-        return lax.dynamic_update_index_in_dim(
-            bt, word | jnp.left_shift(full, sh), w, axis=0)
-
-    def body(carry):
-        if affine:
-            (s, score, m_ring, i_ring, d_ring, off_hist, *bts) = carry
-        else:
-            (s, score, m_ring, off_hist, *bts) = carry
-        prow = lax.rem(s - 1, W)
-        prev_m = lax.dynamic_index_in_dim(m_ring, prow, keepdims=False)
-        prev_off = lax.dynamic_index_in_dim(off_hist, prow, keepdims=False)
-        live = prev_m > _VALID_THRESH
-        if affine:
-            # I/D fronts can outrun M between prunes; center on the union
-            live = (live
-                    | (lax.dynamic_index_in_dim(i_ring, prow, keepdims=False)
-                       > _VALID_THRESH)
-                    | (lax.dynamic_index_in_dim(d_ring, prow, keepdims=False)
-                       > _VALID_THRESH))
-        off = _band_recenter(live, prev_off, Kc, K)
-        ks_c = ks_of(off)
-
-        def rd(ring):
-            return lambda d: _band_read(ring, off_hist, s, d, off, W)
-
-        if affine:
-            out = _next_affine(model, rd(m_ring), pattern, text, plen, tlen,
-                               ks_c, rd(i_ring), rd(d_ring),
-                               with_codes=packed)
-            M_new, I_new, D_new = out[:3]
-            reached = _band_reached(end_front(M_new, I_new, D_new),
-                                    plen, tlen, k_max, off)
-        else:
-            out = _next_linear(model, rd(m_ring), pattern, text, plen, tlen,
-                               ks_c, with_codes=packed)
-            M_new = out[0] if packed else out
-            reached = _band_reached(M_new, plen, tlen, k_max, off)
-        score = jnp.where((score < 0) & reached, s, score)
-
-        keep = keep_mask(heur, M_new, plen[:, None], tlen[:, None], ks_c)
-        if affine:
-            M_new, I_new, D_new = _pruned(keep, M_new, I_new, D_new)
-        else:
-            M_new = _pruned(keep, M_new)
-
-        row = lax.rem(s, W)
-        m_ring = lax.dynamic_update_index_in_dim(m_ring, M_new, row, axis=0)
-        off_hist = lax.dynamic_update_index_in_dim(off_hist, off, row, axis=0)
-        if affine:
-            i_ring = lax.dynamic_update_index_in_dim(i_ring, I_new, row,
-                                                     axis=0)
-            d_ring = lax.dynamic_update_index_in_dim(d_ring, D_new, row,
-                                                     axis=0)
-        if packed and affine:
-            m_bt, i_bt, d_bt = bts
-            cm, ci, cd = out[3:]
-            bts = (pack(m_bt, s, cm, off), pack(i_bt, s, ci, off),
-                   pack(d_bt, s, cd, off))
-        elif packed:
-            (m_bt,) = bts
-            bts = (pack(m_bt, s, out[1], off),)
-        if affine:
-            return (s + 1, score, m_ring, i_ring, d_ring, off_hist, *bts)
-        return (s + 1, score, m_ring, off_hist, *bts)
-
-    def cond(carry):
-        s, score = carry[0], carry[1]
-        return (s <= s_max) & jnp.any(score < 0)
-
-    if affine:
-        init = (jnp.int32(1), score0, m_ring, i_ring, d_ring, off_hist)
-        if packed:
-            init += (m_bt, i_bt, d_bt)
-        fin = lax.while_loop(cond, body, init)
-        s, score = fin[0], fin[1]
-        if packed:
-            return WFAResult(score, None, None, None, s, *fin[6:9])
-        return WFAResult(score, None, None, None, s)
-    init = (jnp.int32(1), score0, m_ring, off_hist)
-    if packed:
-        init += (m_bt,)
-    fin = lax.while_loop(cond, body, init)
-    s, score = fin[0], fin[1]
-    if packed:
-        return WFAResult(score, None, None, None, s, fin[4], None, None)
-    return WFAResult(score, None, None, None, s)
 
 
 def _band_width(band_cap, K):
@@ -556,14 +394,44 @@ def _next_linear(model, read_m, pattern, text, plen, tlen, ks,
     return M_new, code_m
 
 
-def _target_reached(M, plen, tlen, k_max):
-    """[B] bool: does M hold offset == tlen on the final diagonal?"""
+def _target_reached(M, plen, tlen, k_max, off=None):
+    """[B] bool: does M hold offset == tlen on the final diagonal?
+
+    ``off`` ([B]) is the window offset of a compacting band's lane 0."""
     k_final = tlen - plen + k_max                   # index into K axis
+    if off is not None:
+        k_final = k_final - off                     # compact index
     K = M.shape[-1]
     in_band = (k_final >= 0) & (k_final < K)
     idx = jnp.clip(k_final, 0, K - 1)
     val = jnp.take_along_axis(M, idx[:, None], axis=1)[:, 0]
     return in_band & (val >= tlen) & (val > _VALID_THRESH)
+
+
+def _ring_read(ring, s, delta, W, off_hist=None, off=None):
+    """Ring row at score ``s - delta`` (NEG where ``s < delta``).
+
+    Under a compacting band (``off_hist`` [W, B], ``off`` [B]) the row is
+    realigned from the window offset it was stored at to ``off``."""
+    row = lax.rem(jnp.maximum(s - delta, 0), W)
+    r = lax.dynamic_index_in_dim(ring, row, keepdims=False)
+    if off_hist is None:
+        return jnp.where(s >= delta, r, NEG)
+    roff = lax.dynamic_index_in_dim(off_hist, row, keepdims=False)  # [B]
+    Kc = r.shape[-1]
+    idx = jnp.arange(Kc, dtype=jnp.int32)[None, :] + (off - roff)[:, None]
+    ok = (idx >= 0) & (idx < Kc) & (s >= delta)
+    return jnp.where(ok, jnp.take_along_axis(r, jnp.clip(idx, 0, Kc - 1),
+                                             axis=1), NEG)
+
+
+def _pack_codes(bt, s, code):
+    """OR the [B, K] code plane into word s//16 at bit offset 2*(s%16)."""
+    w = s // TRACE_CELLS_PER_WORD
+    sh = 2 * lax.rem(s, TRACE_CELLS_PER_WORD)
+    word = lax.dynamic_index_in_dim(bt, w, keepdims=False)
+    return lax.dynamic_update_index_in_dim(
+        bt, word | jnp.left_shift(code, sh), w, axis=0)
 
 
 def _prep(pattern, text, plen, tlen):
@@ -677,6 +545,106 @@ def wfa_forward(pattern, text, plen, tlen, *, pen, s_max: int,
     return WFAResult(score, None, None, None, s)
 
 
+def _ring_loop(pattern, text, plen, tlen, model, heur, s_max, k_max,
+               band_cap, packed, begin_state="M", end_state="M"):
+    """The ring-buffer score loop behind :func:`wfa_scores` and
+    :func:`wfa_scores_packed`.
+
+    Specialised statically by ``packed`` (trace planes or none), by the
+    model's ring count (3 for affine, 1 for linear; rings and planes are
+    carried as tuples) and by the band width: at full width the loop
+    carries no window offsets; under a compacting band (``band_cap``
+    below K) each step re-centers the window and keeps each ring row's
+    offset (see the compacting-band block comment).  Trace planes stay
+    full width either way, so traceback is oblivious to the band."""
+    B = pattern.shape[0]
+    K = 2 * k_max + 1
+    Kc = _band_width(band_cap, K)
+    band = Kc is not None
+    W = model.window
+    n_rings = 3 if model.kind == "affine" else 1
+    end = "MID".index(end_state)                    # front the target is on
+
+    # data-dependent zero: keeps the while-loop carries' varying-manual-axes
+    # consistent when this solver runs inside shard_map (per-shard loops)
+    taint = (plen.reshape(-1)[0] * 0).astype(jnp.int32)
+    if band:
+        off0s = min(max(k_max - Kc // 2, 0), K - Kc)
+        off0 = jnp.full((B,), off0s, jnp.int32) + taint
+        off_hist = jnp.full((W, B), off0s, jnp.int32) + taint
+        j0, width = k_max - off0s, Kc               # seed lane, in [0, Kc)
+
+        def ks_of(off):
+            return (off[:, None] + jnp.arange(Kc, dtype=jnp.int32)[None, :]
+                    - k_max)
+        ks0 = ks_of(off0)
+    else:
+        off0 = off_hist = None
+        j0, width = k_max, K
+        ks0 = jnp.arange(K, dtype=jnp.int32) - k_max
+
+    seed0 = jnp.full((B, width), NEG, jnp.int32).at[:, j0].set(0)
+    negBK = jnp.full((B, width), NEG, jnp.int32)
+    fronts0 = ((_extend(seed0, pattern, text, plen, tlen, ks0),)
+               + tuple(seed0 if begin_state == st else negBK
+                       for st in "ID")[:n_rings - 1])
+    rings = tuple((jnp.full((W, B, width), NEG, jnp.int32) + taint)
+                  .at[0].set(f) for f in fronts0)
+    reached0 = _target_reached(fronts0[end], plen, tlen, k_max, off0)
+    score0 = jnp.where(reached0, 0, -1)
+    bts = tuple(jnp.zeros((n_trace_words(s_max), B, K), jnp.int32) + taint
+                for _ in range(n_rings)) if packed else ()
+
+    def body(carry):
+        s, score, rings, off_hist, bts = carry
+        if band:
+            prow = lax.rem(s - 1, W)
+            # I/D fronts can outrun M between prunes; center on the union
+            live = functools.reduce(jnp.logical_or, (
+                lax.dynamic_index_in_dim(r, prow, keepdims=False)
+                > _VALID_THRESH for r in rings))
+            prev_off = lax.dynamic_index_in_dim(off_hist, prow,
+                                                keepdims=False)
+            off = _band_recenter(live, prev_off, Kc, K)
+            ks = ks_of(off)
+        else:
+            off, ks = None, ks0
+        reads = [lambda d, r=r: _ring_read(r, s, d, W, off_hist, off)
+                 for r in rings]
+        if n_rings == 3:
+            out = _next_affine(model, reads[0], pattern, text, plen, tlen,
+                               ks, reads[1], reads[2], with_codes=packed)
+        else:
+            out = _next_linear(model, reads[0], pattern, text, plen, tlen,
+                               ks, with_codes=packed)
+            out = out if packed else (out,)
+        fronts, codes = out[:n_rings], out[n_rings:]
+        reached = _target_reached(fronts[end], plen, tlen, k_max, off)
+        score = jnp.where((score < 0) & reached, s, score)
+        keep = keep_mask(heur, fronts[0], plen[:, None], tlen[:, None],
+                         ks if band else ks[None, :])
+        if keep is not None:
+            fronts = tuple(jnp.where(keep, f, NEG) for f in fronts)
+
+        row = lax.rem(s, W)
+        rings = tuple(lax.dynamic_update_index_in_dim(r, f, row, axis=0)
+                      for r, f in zip(rings, fronts))
+        if band:
+            off_hist = lax.dynamic_update_index_in_dim(off_hist, off, row,
+                                                       axis=0)
+            codes = tuple(_band_scatter(c, off, K) for c in codes)
+        bts = tuple(_pack_codes(bt, s, c) for bt, c in zip(bts, codes))
+        return s + 1, score, rings, off_hist, bts
+
+    def cond(carry):
+        s, score = carry[0], carry[1]
+        return (s <= s_max) & jnp.any(score < 0)
+
+    s, score, _, _, bts = lax.while_loop(
+        cond, body, (jnp.int32(1), score0, rings, off_hist, bts))
+    return WFAResult(score, None, None, None, s, *bts)
+
+
 @functools.partial(jax.jit, static_argnames=("pen", "s_max", "k_max", "heur",
                                              "band_cap"))
 def wfa_scores(pattern, text, plen, tlen, *, pen, s_max: int,
@@ -697,77 +665,8 @@ def wfa_scores(pattern, text, plen, tlen, *, pen, s_max: int,
     approximation is explicitly wanted).
     """
     model, heur = _resolve(pen, heur)
-    pattern, text, plen, tlen = _prep(pattern, text, plen, tlen)
-    B = pattern.shape[0]
-    K = 2 * k_max + 1
-    Kc = _band_width(band_cap, K)
-    if Kc is not None:
-        return _scores_band(pattern, text, plen, tlen, model, heur,
-                            s_max, k_max, Kc, False, "M", "M")
-    W = model.window
-    ks = jnp.arange(K, dtype=jnp.int32) - k_max
-    affine = model.kind == "affine"
-
-    # data-dependent zero: keeps the while-loop carries' varying-manual-axes
-    # consistent when this solver runs inside shard_map (per-shard loops)
-    taint = (plen.reshape(-1)[0] * 0).astype(jnp.int32)
-    m_ring = jnp.full((W, B, K), NEG, jnp.int32) + taint
-
-    M0 = jnp.full((B, K), NEG, jnp.int32).at[:, k_max].set(0)
-    M0 = _extend(M0, pattern, text, plen, tlen, ks)
-    m_ring = m_ring.at[0].set(M0)
-    score0 = jnp.where(_target_reached(M0, plen, tlen, k_max), 0, -1)
-
-    def read(ring, s, delta):
-        row = lax.dynamic_index_in_dim(ring, lax.rem(jnp.maximum(s - delta, 0),
-                                                     W), keepdims=False)
-        return jnp.where(s >= delta, row, NEG)
-
-    if affine:
-        i_ring = jnp.full((W, B, K), NEG, jnp.int32) + taint
-        d_ring = jnp.full((W, B, K), NEG, jnp.int32) + taint
-
-        def body(carry):
-            s, score, m_ring, i_ring, d_ring = carry
-            M_new, I_new, D_new = _next_affine(
-                model, lambda d: read(m_ring, s, d), pattern, text,
-                plen, tlen, ks, lambda d: read(i_ring, s, d),
-                lambda d: read(d_ring, s, d))
-            reached = _target_reached(M_new, plen, tlen, k_max)
-            score = jnp.where((score < 0) & reached, s, score)
-            M_new, I_new, D_new = _prune_step(heur, plen, tlen, ks,
-                                              M_new, I_new, D_new)
-            row = lax.rem(s, W)
-            m_ring = lax.dynamic_update_index_in_dim(m_ring, M_new, row, axis=0)
-            i_ring = lax.dynamic_update_index_in_dim(i_ring, I_new, row, axis=0)
-            d_ring = lax.dynamic_update_index_in_dim(d_ring, D_new, row, axis=0)
-            return s + 1, score, m_ring, i_ring, d_ring
-
-        def cond(carry):
-            s, score, *_ = carry
-            return (s <= s_max) & jnp.any(score < 0)
-
-        s, score, *_ = lax.while_loop(
-            cond, body, (jnp.int32(1), score0, m_ring, i_ring, d_ring))
-    else:
-        def body(carry):
-            s, score, m_ring = carry
-            M_new = _next_linear(model, lambda d: read(m_ring, s, d),
-                                 pattern, text, plen, tlen, ks)
-            reached = _target_reached(M_new, plen, tlen, k_max)
-            score = jnp.where((score < 0) & reached, s, score)
-            M_new = _prune_step(heur, plen, tlen, ks, M_new)
-            m_ring = lax.dynamic_update_index_in_dim(m_ring, M_new,
-                                                     lax.rem(s, W), axis=0)
-            return s + 1, score, m_ring
-
-        def cond(carry):
-            s, score, _ = carry
-            return (s <= s_max) & jnp.any(score < 0)
-
-        s, score, _ = lax.while_loop(
-            cond, body, (jnp.int32(1), score0, m_ring))
-    return WFAResult(score, None, None, None, s)
+    return _ring_loop(*_prep(pattern, text, plen, tlen), model, heur,
+                      s_max, k_max, band_cap, packed=False)
 
 
 @functools.partial(jax.jit, static_argnames=("pen", "s_max", "k_max", "heur",
@@ -780,11 +679,11 @@ def wfa_scores_packed(pattern, text, plen, tlen, *, pen,
     """Ring-buffer batched WFA *with* a packed backtrace.
 
     Identical wavefront recurrence and rolling-window memory discipline as
-    :func:`wfa_scores`, plus ``[n_trace_words, B, K]`` int32 arrays of
-    2-bit provenance codes (16 score steps per word, OR-accumulated in the
-    score loop) — three planes for affine models, one for linear.
-    ``core.cigar`` decodes them into exact CIGARs without ever
-    materializing the full offset history.
+    :func:`wfa_scores` (the same loop), plus ``[n_trace_words, B, K]``
+    int32 arrays of 2-bit provenance codes (16 score steps per word,
+    OR-accumulated in the score loop) — three planes for affine models,
+    one for linear.  ``core.cigar`` decodes them into exact CIGARs without
+    ever materializing the full offset history.
 
     ``begin_state``/``end_state`` as in :func:`wfa_forward` (BiWFA
     sub-alignment boundaries, affine only).  The gap seed cell carries no
@@ -796,105 +695,9 @@ def wfa_scores_packed(pattern, text, plen, tlen, *, pen,
     """
     model, heur = _resolve(pen, heur)
     _check_states(model, begin_state, end_state)
-    pattern, text, plen, tlen = _prep(pattern, text, plen, tlen)
-    B = pattern.shape[0]
-    K = 2 * k_max + 1
-    Kc = _band_width(band_cap, K)
-    if Kc is not None:
-        return _scores_band(pattern, text, plen, tlen, model, heur,
-                            s_max, k_max, Kc, True, begin_state, end_state)
-    W = model.window
-    NW = n_trace_words(s_max)
-    ks = jnp.arange(K, dtype=jnp.int32) - k_max
-    affine = model.kind == "affine"
-
-    # data-dependent zero: keeps while-loop carries shard_map-compatible
-    # (same trick as wfa_scores)
-    taint = (plen.reshape(-1)[0] * 0).astype(jnp.int32)
-    m_ring = jnp.full((W, B, K), NEG, jnp.int32) + taint
-    m_bt = jnp.zeros((NW, B, K), jnp.int32) + taint
-
-    seed0 = jnp.full((B, K), NEG, jnp.int32).at[:, k_max].set(0)
-    M0 = _extend(seed0, pattern, text, plen, tlen, ks)
-    m_ring = m_ring.at[0].set(M0)
-    negBK = jnp.full((B, K), NEG, jnp.int32)
-    I0 = seed0 if (affine and begin_state == "I") else negBK
-    D0 = seed0 if (affine and begin_state == "D") else negBK
-
-    def end_front(M, I, D):
-        return {"M": M, "I": I, "D": D}[end_state]
-
-    front0 = M0 if not affine else end_front(M0, I0, D0)
-    score0 = jnp.where(_target_reached(front0, plen, tlen, k_max), 0, -1)
-
-    def read(ring, s, delta):
-        row = lax.dynamic_index_in_dim(ring, lax.rem(jnp.maximum(s - delta, 0),
-                                                     W), keepdims=False)
-        return jnp.where(s >= delta, row, NEG)
-
-    def pack(bt, s, code):
-        """OR the [B, K] code plane into word s//16 at bit offset 2*(s%16)."""
-        w = s // TRACE_CELLS_PER_WORD
-        off = 2 * lax.rem(s, TRACE_CELLS_PER_WORD)
-        word = lax.dynamic_index_in_dim(bt, w, keepdims=False)
-        return lax.dynamic_update_index_in_dim(
-            bt, word | jnp.left_shift(code, off), w, axis=0)
-
-    if affine:
-        i_ring = (jnp.full((W, B, K), NEG, jnp.int32) + taint).at[0].set(I0)
-        d_ring = (jnp.full((W, B, K), NEG, jnp.int32) + taint).at[0].set(D0)
-        i_bt = jnp.zeros((NW, B, K), jnp.int32) + taint
-        d_bt = jnp.zeros((NW, B, K), jnp.int32) + taint
-
-        def body(carry):
-            s, score, m_ring, i_ring, d_ring, m_bt, i_bt, d_bt = carry
-            M_new, I_new, D_new, cm, ci, cd = _next_affine(
-                model, lambda d: read(m_ring, s, d), pattern, text,
-                plen, tlen, ks, lambda d: read(i_ring, s, d),
-                lambda d: read(d_ring, s, d), with_codes=True)
-            reached = _target_reached(end_front(M_new, I_new, D_new),
-                                      plen, tlen, k_max)
-            score = jnp.where((score < 0) & reached, s, score)
-            M_new, I_new, D_new = _prune_step(heur, plen, tlen, ks,
-                                              M_new, I_new, D_new)
-            row = lax.rem(s, W)
-            m_ring = lax.dynamic_update_index_in_dim(m_ring, M_new, row, axis=0)
-            i_ring = lax.dynamic_update_index_in_dim(i_ring, I_new, row, axis=0)
-            d_ring = lax.dynamic_update_index_in_dim(d_ring, D_new, row, axis=0)
-            m_bt = pack(m_bt, s, cm)
-            i_bt = pack(i_bt, s, ci)
-            d_bt = pack(d_bt, s, cd)
-            return s + 1, score, m_ring, i_ring, d_ring, m_bt, i_bt, d_bt
-
-        def cond(carry):
-            s, score, *_ = carry
-            return (s <= s_max) & jnp.any(score < 0)
-
-        s, score, _, _, _, m_bt, i_bt, d_bt = lax.while_loop(
-            cond, body, (jnp.int32(1), score0, m_ring, i_ring, d_ring,
-                         m_bt, i_bt, d_bt))
-        return WFAResult(score, None, None, None, s, m_bt, i_bt, d_bt)
-
-    def body(carry):
-        s, score, m_ring, m_bt = carry
-        M_new, cm = _next_linear(model, lambda d: read(m_ring, s, d),
-                                 pattern, text, plen, tlen, ks,
-                                 with_codes=True)
-        reached = _target_reached(M_new, plen, tlen, k_max)
-        score = jnp.where((score < 0) & reached, s, score)
-        M_new = _prune_step(heur, plen, tlen, ks, M_new)
-        m_ring = lax.dynamic_update_index_in_dim(m_ring, M_new,
-                                                 lax.rem(s, W), axis=0)
-        m_bt = pack(m_bt, s, cm)
-        return s + 1, score, m_ring, m_bt
-
-    def cond(carry):
-        s, score, *_ = carry
-        return (s <= s_max) & jnp.any(score < 0)
-
-    s, score, _, m_bt = lax.while_loop(
-        cond, body, (jnp.int32(1), score0, m_ring, m_bt))
-    return WFAResult(score, None, None, None, s, m_bt, None, None)
+    return _ring_loop(*_prep(pattern, text, plen, tlen), model, heur,
+                      s_max, k_max, band_cap, packed=True,
+                      begin_state=begin_state, end_state=end_state)
 
 
 class BidirMeetResult(NamedTuple):
@@ -1014,9 +817,7 @@ def wfa_bidir_meet(pattern, text, plen, tlen, starget, *, pen, s_max: int,
         rd = ring0(seed if end_state == "D" else negBK)
 
     def read(ring, s, delta):
-        row = lax.dynamic_index_in_dim(
-            ring, lax.rem(jnp.maximum(s - delta, 0), Wd), keepdims=False)
-        return jnp.where(s >= delta, row, NEG)
+        return _ring_read(ring, s, delta, Wd)
 
     # complement-diagonal gather: rev K-index addressing the same cell
     jj = jnp.arange(K, dtype=jnp.int32)[None, :]

@@ -12,8 +12,6 @@ Tuning knobs (all optional, threaded from
   auto-default (8: one int32 sublane tile on TPU, and the measured best in
   interpret mode, where a small block keeps the per-block early exit
   effective).
-* ``gather`` — extension word fetch, ``"index"``/``"onehot"``
-  (default: index under interpret, onehot compiled — see ``kernel.py``).
 * ``band_cap`` — compacting-band width; lane-aligned here (rounded up to
   128) before reaching the kernel, so the compact rings stay legal TPU
   tiles.  None = full width.
@@ -22,7 +20,8 @@ Tuning knobs (all optional, threaded from
 is given (``core.engine.fetch_char_bits``).  The wrapper packs both
 sequence arrays into words of ``32 // char_bits`` characters inside the
 same jit (:func:`pack_words`), so the extend loop compares that many
-characters per trip.
+characters per trip.  Nor is the fetch itself: the kernel has one, the
+same interpreted and compiled (``kernel.py``).
 """
 from __future__ import annotations
 
@@ -128,8 +127,8 @@ def _block_sums(steps, trips, bp: int):
 def wfa_align(pattern, text, plen, tlen, *, pen, s_max: int,
               k_max: int, block_pairs: Optional[int] = None,
               interpret: Optional[bool] = None, heur=None,
-              gather: Optional[str] = None, char_bits: int = 32,
-              band_cap: Optional[int] = None, counts: bool = False):
+              char_bits: int = 32, band_cap: Optional[int] = None,
+              counts: bool = False):
     """Batched WFA scores via the Pallas kernel.
 
     pattern/text: [B, L*] int; plen/tlen: [B] int.  Returns [B] int32 costs
@@ -152,7 +151,7 @@ def wfa_align(pattern, text, plen, tlen, *, pen, s_max: int,
     score, steps, trips = wfa_pallas(
         pack_words(pattern, char_bits), pack_words(text, char_bits), plen2,
         tlen2, pen=pen, s_max=s_max, k_pad=k_pad, block_pairs=bp,
-        interpret=interpret, heur=scoring.as_heuristic(heur), gather=gather,
+        interpret=interpret, heur=scoring.as_heuristic(heur),
         char_bits=char_bits, band_cap=_band_lanes(band_cap, k_pad))
     if counts:
         return (score[:B, 0],) + _block_sums(steps, trips, bp)
@@ -162,8 +161,8 @@ def wfa_align(pattern, text, plen, tlen, *, pen, s_max: int,
 def wfa_align_trace(pattern, text, plen, tlen, *, pen, s_max: int,
                     k_max: int, block_pairs: Optional[int] = None,
                     interpret: Optional[bool] = None, heur=None,
-                    gather: Optional[str] = None, char_bits: int = 32,
-                    band_cap: Optional[int] = None, counts: bool = False):
+                    char_bits: int = 32, band_cap: Optional[int] = None,
+                    counts: bool = False):
     """Batched WFA scores *plus* packed backtrace via the Pallas kernel.
 
     Same padding contract as :func:`wfa_align`; returns
@@ -186,8 +185,7 @@ def wfa_align_trace(pattern, text, plen, tlen, *, pen, s_max: int,
         pack_words(pattern, char_bits), pack_words(text, char_bits), plen2,
         tlen2, pen=pen, s_max=s_max, k_pad=k_pad, block_pairs=bp,
         interpret=interpret, trace=True, heur=scoring.as_heuristic(heur),
-        gather=gather, char_bits=char_bits,
-        band_cap=_band_lanes(band_cap, k_pad))
+        char_bits=char_bits, band_cap=_band_lanes(band_cap, k_pad))
     score, steps, trips, m_bt, *gaps = out
     i_bt, d_bt = (g[:, :B, :] for g in gaps) if gaps else (None, None)
     res = (score[:B, 0], m_bt[:, :B, :], i_bt, d_bt)
@@ -199,50 +197,3 @@ def wfa_align_trace(pattern, text, plen, tlen, *, pen, s_max: int,
 def wfa_align_np(pattern, text, plen, tlen, **kw):
     return np.asarray(wfa_align(pattern, text, plen, tlen, **kw))
 
-
-def wfa_bidir_meet_kernel(pattern, text, plen, tlen, starget, *, pen,
-                          s_max: int, k_max: int, heur=None,
-                          begin_state: str = "M", end_state: str = "M",
-                          block_pairs: Optional[int] = None,
-                          interpret: Optional[bool] = None):
-    """Device-resident BiWFA meet search via the fused Pallas grid.
-
-    Drop-in for ``core.wavefront.wfa_bidir_meet`` (same signature and
-    ``BidirMeetResult``), selected by the ``kernel`` backend for
-    ``trace_variant="bidir"`` meet waves: both fronts' rings live in VMEM
-    scratch and each grid program exits as soon as its own block's pairs
-    have met, instead of the jnp solver's whole-batch early-exit.  The
-    meet detector's per-pair ring reads are real gathers, which Mosaic
-    refuses ("Only 2D gather is supported"), so the fused grid runs in
-    interpret mode only; asking for a compiled run raises.  The engine
-    picks the jnp solver for meet waves on the TPU.
-    """
-    from repro.core.wavefront import BidirMeetResult, _reverse_rows
-    from repro.kernels.wfa.kernel import wfa_meet_pallas
-
-    if interpret is None:
-        interpret = default_interpret()
-    if not interpret:
-        raise NotImplementedError(
-            "the fused BiWFA meet kernel does not compile for the TPU: its "
-            "per-pair ring reads are gathers Mosaic cannot lower; use "
-            "core.wavefront.wfa_bidir_meet there")
-    bp = resolve_block_pairs(block_pairs)
-    pattern2, text2, plen2, tlen2, B = _prep(pattern, text, plen, tlen, bp)
-    starget2 = _pad_axis(
-        jnp.asarray(starget, jnp.int32).reshape(-1)[:, None], 0,
-        pattern2.shape[0])
-    k_pad = _round_up(2 * k_max + 1, LANE)
-    pat_rev = _reverse_rows(pattern2, plen2[:, 0])
-    txt_rev = _reverse_rows(text2, tlen2[:, 0])
-
-    (score, steps, state, a, b, k, h,
-     safe) = wfa_meet_pallas(pattern2, text2, pat_rev, txt_rev, plen2,
-                             tlen2, starget2, pen=pen, s_max=s_max,
-                             k_pad=k_pad, block_pairs=bp,
-                             interpret=interpret,
-                             heur=scoring.as_heuristic(heur),
-                             begin_state=begin_state, end_state=end_state)
-    return BidirMeetResult(score[:B, 0], jnp.max(steps), state[:B, 0],
-                           a[:B, 0], b[:B, 0], k[:B, 0], h[:B, 0],
-                           safe[:B, 0])

@@ -84,6 +84,32 @@ def test_bidir_parity_recursive(rng, pen, backend, div):
     assert res.stats.n_meet_unmet == 0
 
 
+@pytest.fixture(scope="module")
+def ring_meets():
+    """An indel-heavy affine batch whose costs need meet waves under a
+    trace budget of 1,000 cells, and the ring backend's bidir result."""
+    pen = GapAffine(4, 6, 2)
+    ps, ts = _divergent_pairs(np.random.default_rng(16), 6, 160, 0.10)
+    want = AlignmentEngine(pen, backend="ring", trace_budget=1000).align(
+        ps, ts, output="cigar", trace_variant="bidir")
+    return pen, ps, ts, want
+
+
+@pytest.mark.parametrize("backend", ["ref", "kernel", "shardmap"])
+def test_bidir_meet_waves_on_every_backend(ring_meets, backend):
+    # every backend's meet waves run the one jnp meet solver and its
+    # children trace to the same codes, so a small budget gives ring's
+    # CIGARs op for op, with every meet found
+    pen, ps, ts, want = ring_meets
+    eng = AlignmentEngine(pen, backend=backend, trace_budget=1000)
+    got = _assert_bidir_exact(eng, pen, ps, ts)
+    assert any("bidir_meet" in key for key in eng._cache)
+    assert want.stats.n_meet_unmet == got.stats.n_meet_unmet == 0
+    np.testing.assert_array_equal(got.scores, want.scores)
+    for a, b in zip(got.cigars, want.cigars):
+        np.testing.assert_array_equal(a, b)
+
+
 @pytest.mark.parametrize("pen", MODELS, ids=MODEL_IDS)
 def test_bidir_base_case_direct(rng, pen):
     # default budget: short pairs fit the packed traceback outright, so the

@@ -1,9 +1,10 @@
 """Fused-grid kernel features: heuristics under trace, compacting band,
-gather modes, the in-grid BiWFA meet, and engine ``backend_opts`` plumbing.
+the packed fetch against the oracle, BiWFA meet waves on the kernel
+backend, and engine ``backend_opts`` plumbing.
 
 Everything here is exact-equality: scores are integers and the compacting
-band / gather / blocking knobs are all contracted to be bit-identical to
-the full-width reference whenever the live span fits the band.
+band / blocking knobs are all contracted to be bit-identical to the
+full-width reference whenever the live span fits the band.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -12,8 +13,10 @@ import pytest
 from repro.core import cigar as cigar_mod
 from repro.core import wavefront as wf
 from repro.core.engine import AlignmentEngine, problem_bounds
+from repro.core.gotoh import score_cigar
 from repro.core.penalties import DEFAULT, Penalties
-from repro.core.scoring import AdaptiveBand, Edit, ZDrop, as_model
+from repro.core.scoring import (AdaptiveBand, Edit, GapAffine, GapLinear,
+                                ZDrop, as_model)
 from repro.data.reads import ReadPairSpec, generate_pairs
 from repro.kernels.wfa import ops as kops
 from repro.kernels.wfa import ref_scores
@@ -135,73 +138,54 @@ def test_band_scores_offset_correctness():
     np.testing.assert_array_equal(full, band)
 
 
-# -- gather / blocking invariance -------------------------------------------
+@pytest.mark.parametrize("band", [False, True], ids=["full", "band"])
+@pytest.mark.parametrize("pen", [GapAffine(4, 6, 2), GapLinear(4, 2), Edit()],
+                         ids=["affine", "linear", "edit"])
+def test_ring_entry_points_agree(pen, band):
+    """``wfa_scores`` and ``wfa_scores_packed`` share one score loop: under
+    AdaptiveBand, at full width and under a compacting band narrower than
+    K, they return the same scores and step counts, and the packed planes
+    decode to alignments of those scores."""
+    heur = AdaptiveBand(min_wf_len=4, max_distance_diff=10)
+    P, plen, T, tlen, s_max, k_max = _pairs(12, 72, 0.08, 31)
+    cap = heur.band_cap(2 * k_max + 1) if band else None
+    assert cap is None or cap < 2 * k_max + 1
+    args = (jnp.asarray(P), jnp.asarray(T), jnp.asarray(plen),
+            jnp.asarray(tlen))
+    kw = dict(pen=pen, s_max=s_max, k_max=k_max, heur=heur, band_cap=cap)
+    score = wf.wfa_scores(*args, **kw)
+    packed = wf.wfa_scores_packed(*args, **kw)
+    np.testing.assert_array_equal(np.asarray(score.score),
+                                  np.asarray(packed.score))
+    assert int(score.n_steps) == int(packed.n_steps)
+    got = np.asarray(packed.score)
+    assert (got >= 0).all()
+    ops = cigar_mod.traceback_packed_batch(packed, pen, P, T, plen, tlen)
+    for i, c in enumerate(ops):
+        cost, ci, cj, ok = score_cigar(c, P[i, :plen[i]], T[i, :tlen[i]],
+                                       as_model(pen))
+        assert ok and (ci, cj) == (plen[i], tlen[i]), i
+        assert cost == got[i], (i, cost, got[i])
+
+
+# -- the one fetch against the oracle ---------------------------------------
 
 
 @pytest.mark.parametrize("pen", [DEFAULT, Penalties(1, 0, 1)],
                          ids=["affine", "linear"])
 def test_gather_mode_invariance(pen):
-    """'index' and 'onehot' char fetches are the same function."""
+    """The kernel's one character fetch, the compare-and-reduce the chip
+    runs, returns ``wfa_forward``'s scores at 16 characters per trip and
+    at one."""
     P, plen, T, tlen, s_max, k_max = _pairs(8, 32, 0.06, 25)
-    idx = np.asarray(kops.wfa_align(P, T, plen, tlen, pen=pen, s_max=s_max,
-                                    k_max=k_max, gather="index"))
-    oh = np.asarray(kops.wfa_align(P, T, plen, tlen, pen=pen, s_max=s_max,
-                                   k_max=k_max, gather="onehot"))
-    np.testing.assert_array_equal(idx, oh)
-
-
-# -- device-resident BiWFA meet ---------------------------------------------
-
-
-@pytest.mark.parametrize("pen,states",
-                         [(DEFAULT, ("M", "M")), (DEFAULT, ("I", "D")),
-                          (Edit(), ("M", "M"))],
-                         ids=["affine-MM", "affine-ID", "linear-MM"])
-def test_meet_kernel_parity(pen, states):
-    """The fused meet kernel returns the jnp solver's result field for
-    field — same breakpoint, same safety flag, same unmet handling.
-    (I/D boundary states exist only under gap-affine models.)"""
-    begin, end = states
-    P, plen, T, tlen, s_max, k_max = _pairs(10, 56, 0.08, 27)
-    starget = wf.wfa_scores_packed(jnp.asarray(P), jnp.asarray(T),
-                                   jnp.asarray(plen), jnp.asarray(tlen),
-                                   pen=pen, s_max=s_max, k_max=k_max,
-                                   begin_state=begin, end_state=end).score
-    ref = wf.wfa_bidir_meet(P, T, plen, tlen, starget, pen=pen, s_max=s_max,
-                            k_max=k_max, begin_state=begin, end_state=end)
-    got = kops.wfa_bidir_meet_kernel(P, T, plen, tlen, starget, pen=pen,
-                                     s_max=s_max, k_max=k_max,
-                                     begin_state=begin, end_state=end)
-    for field in ("score", "meet_state", "meet_a", "meet_b", "meet_k",
-                  "meet_h", "meet_safe"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(ref, field)), np.asarray(getattr(got, field)),
-            err_msg=field)
-
-
-def test_meet_kernel_block_invariance():
-    P, plen, T, tlen, s_max, k_max = _pairs(10, 48, 0.08, 28)
-    starget = wf.wfa_scores(P, T, plen, tlen, pen=DEFAULT, s_max=s_max,
-                            k_max=k_max).score
-    a = kops.wfa_bidir_meet_kernel(P, T, plen, tlen, starget, pen=DEFAULT,
-                                   s_max=s_max, k_max=k_max, block_pairs=4)
-    b = kops.wfa_bidir_meet_kernel(P, T, plen, tlen, starget, pen=DEFAULT,
-                                   s_max=s_max, k_max=k_max, block_pairs=16)
-    for field in ("score", "meet_state", "meet_a", "meet_b", "meet_k",
-                  "meet_h", "meet_safe"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(a, field)), np.asarray(getattr(b, field)),
-            err_msg=field)
-
-
-def test_meet_kernel_refuses_compiled_mode():
-    """The fused meet kernel never hands a compiled request to another
-    solver: it raises, and the engine picks the jnp solver on the TPU."""
-    P, plen, T, tlen, s_max, k_max = _pairs(4, 32, 0.08, 29)
-    with pytest.raises(NotImplementedError, match="does not compile"):
-        kops.wfa_bidir_meet_kernel(P, T, plen, tlen, np.zeros(4, np.int32),
-                                   pen=DEFAULT, s_max=s_max, k_max=k_max,
-                                   interpret=False)
+    want = np.asarray(wf.wfa_forward(P, T, plen, tlen, pen=pen, s_max=s_max,
+                                     k_max=k_max, keep_history=False).score)
+    assert (want >= 0).all()
+    for char_bits in (2, 32):
+        got = np.asarray(kops.wfa_align(P, T, plen, tlen, pen=pen,
+                                        s_max=s_max, k_max=k_max,
+                                        char_bits=char_bits))
+        np.testing.assert_array_equal(got, want, err_msg=f"{char_bits}")
 
 
 # -- engine backend_opts plumbing -------------------------------------------
@@ -264,15 +248,25 @@ def test_engine_band_cap_auto_exact_is_noop(seqs):
     np.testing.assert_array_equal(plain.scores, auto.scores)
 
 
-def test_engine_kernel_bidir_meet_variant(seqs):
-    """trace_variant='bidir' on the kernel backend routes meet waves
-    through the fused meet kernel and still yields packed-identical
-    CIGARs."""
-    pats, txts = seqs
-    packed = AlignmentEngine(backend="kernel").align(pats, txts,
-                                                     output="cigar")
-    bidir = AlignmentEngine(backend="kernel").align(
+@pytest.mark.parametrize("pen", [GapAffine(4, 6, 2), GapLinear(4, 2),
+                                 Edit()], ids=["affine", "linear", "edit"])
+def test_engine_kernel_bidir_meet_variant(pen):
+    """trace_variant='bidir' on the kernel backend: its meet waves run the
+    jnp meet solver, as on every backend, so its CIGARs are the ring
+    backend's op for op and its scores the packed trace's.  Pairs at 10%
+    divergence and a small trace budget make the driver meet before it
+    traces."""
+    P, plen, T, tlen = generate_pairs(
+        ReadPairSpec(n_pairs=6, read_len=100, edit_frac=0.10, seed=30))
+    pats, txts = _strs(P, plen), _strs(T, tlen)
+    packed = AlignmentEngine(pen, backend="kernel").align(pats, txts,
+                                                          output="cigar")
+    ring = AlignmentEngine(pen, backend="ring", trace_budget=600).align(
         pats, txts, output="cigar", trace_variant="bidir")
+    eng = AlignmentEngine(pen, backend="kernel", trace_budget=600)
+    bidir = eng.align(pats, txts, output="cigar", trace_variant="bidir")
+    assert any("bidir_meet" in key for key in eng._cache)
+    assert bidir.stats.n_meet_unmet == 0
     np.testing.assert_array_equal(packed.scores, bidir.scores)
-    for a, b in zip(packed.cigars, bidir.cigars):
+    for a, b in zip(ring.cigars, bidir.cigars):
         np.testing.assert_array_equal(a, b)

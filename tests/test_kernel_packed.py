@@ -1,10 +1,10 @@
 """The kernel's packed extend fetch: 16 (2-bit), 4 (8-bit) or 1 (32-bit)
 characters compared per trip, all with the same result.
 
-Each case runs the interpreted kernel at one fetch width, gather mode and
-output mode, and checks that scores and every packed trace plane equal
-the one-character fetch's bit for bit, and that the scores equal the
-Gotoh oracle.  The rows put match runs against the word boundaries and
+Each case runs the interpreted kernel (the chip's fetch) at one fetch
+width, penalty model and output mode, and checks that scores and every
+packed trace plane equal the one-character fetch's bit for bit, and that
+the scores equal the Gotoh oracle.  The rows put match runs against the word boundaries and
 the sequence ends; lowercase, N and other byte codes run at the 8-bit
 and 32-bit widths only, the widths the session picks for them.
 """
@@ -14,7 +14,7 @@ import pytest
 from repro.core.engine import AlignmentEngine, fetch_char_bits, pack_batch
 from repro.core.gotoh import gotoh_score_vec
 from repro.core.penalties import DEFAULT
-from repro.core.scoring import AdaptiveBand
+from repro.core.scoring import AdaptiveBand, GapLinear
 from repro.data.reads import ReadPairSpec, generate_pairs
 from repro.kernels.wfa import ops as kops
 from repro.kernels.wfa.kernel import _zero_fields
@@ -97,40 +97,41 @@ def _stack(a, b):
             np.concatenate([fit(T1), fit(T2)]), np.concatenate([tl1, tl2]))
 
 
-def _run(batch, char_bits, gather, trace, k_max=K_MAX, **kw):
+def _run(batch, char_bits, trace, pen=DEFAULT, k_max=K_MAX, **kw):
     P, plen, T, tlen = batch
     fn = kops.wfa_align_trace if trace else kops.wfa_align
-    out = fn(P, T, plen, tlen, pen=DEFAULT, s_max=S_MAX, k_max=k_max,
-             gather=gather, char_bits=char_bits, interpret=True, **kw)
+    out = fn(P, T, plen, tlen, pen=pen, s_max=S_MAX, k_max=k_max,
+             char_bits=char_bits, interpret=True, **kw)
     out = out if trace else (out,)
     return [None if a is None else np.asarray(a) for a in out]
 
 
-def _gotoh(batch):
+def _gotoh(batch, pen=DEFAULT):
     P, plen, T, tlen = batch
     return np.array([gotoh_score_vec(P[i, :plen[i]], T[i, :tlen[i]],
-                                     DEFAULT) for i in range(len(plen))])
+                                     pen) for i in range(len(plen))])
 
 
 @pytest.mark.parametrize("output", ["score", "trace"])
-@pytest.mark.parametrize("gather", ["index", "onehot"])
+@pytest.mark.parametrize("pen", [DEFAULT, GapLinear(4, 2)],
+                         ids=["affine", "linear"])
 @pytest.mark.parametrize("char_bits", [2, 8, 32])
-def test_packed_fetch_is_exact(char_bits, gather, output):
+def test_packed_fetch_is_exact(char_bits, pen, output):
     trace = output == "trace"
     batches = [_acgt_batch()] + ([_mixed_batch()] if char_bits != 2 else [])
     for batch in batches:
-        got = _run(batch, char_bits, gather, trace)
-        want = _run(batch, 32, "index", trace)
+        got = _run(batch, char_bits, trace, pen)
+        want = _run(batch, 32, trace, pen)
         for g, w in zip(got, want):
             np.testing.assert_array_equal(g, w)
-        np.testing.assert_array_equal(got[0], _gotoh(batch))
+        np.testing.assert_array_equal(got[0], _gotoh(batch, pen))
     # the compacting band (128 of 256 lanes): pruned, so no oracle, but
     # the same at any width
     heur = AdaptiveBand(min_wf_len=4, max_distance_diff=10)
     kw = dict(k_max=2 * K_MAX, heur=heur, band_cap=heur.band_cap(161))
     assert kops._band_lanes(kw["band_cap"], 256) == 128
-    got = _run(batches[0], char_bits, gather, trace, **kw)
-    want = _run(batches[0], 32, "index", trace, **kw)
+    got = _run(batches[0], char_bits, trace, pen, **kw)
+    want = _run(batches[0], 32, trace, pen, **kw)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(g, w)
 
