@@ -1,8 +1,8 @@
 """Kernel-vs-ring gap tracker: throughput ratio + parity + pruning gate.
 
-The interpreted Pallas kernel runs the chip's fetch, a compare-and-reduce
-that materializes ``[B, 128, L]`` per extend trip and that interpret
-mode executes eagerly.  This suite tracks its speed against the jnp ring
+The interpreted Pallas kernel runs the chip's fetch, a select tree over
+``[L, 8, 128]`` word slabs per extend trip, which interpret mode
+executes as XLA ops on the CPU.  This suite tracks its speed against the jnp ring
 solver on every push (a CPU ratio, not a chip figure), plus two
 correctness properties:
 

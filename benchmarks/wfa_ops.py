@@ -1,5 +1,5 @@
 """Micro-decomposition of the WFA inner loop (DPU-kernel ops): cost of one
-score step (recurrences) vs one extension trip vs the one-hot char fetch —
+score step (recurrences) vs one extension trip vs a gather char fetch —
 the quantities the Pallas kernel's VMEM schedule is built around."""
 from __future__ import annotations
 

@@ -13,10 +13,16 @@ Hardware mapping (DESIGN.md §2):
   every arithmetic op is a full-width vector op;
 * no communication between grid programs ≙ no inter-DPU communication.
 
-Extension fetches characters with one compare-and-reduce
-(``sum_l [idx == l] * seq[l]``), the formulation a TPU VPU supports (it
-has no per-lane gather).  Interpret mode runs the same fetch, so the CPU
-tests exercise the program the chip runs.
+Extension fetches each lane's word with a binary select tree (a TPU VPU
+has no per-lane gather).  Once per grid block each sequence is laid out
+in VMEM as ``[L, BP, 128]``: slab ``l`` holds word ``l`` of every pair on
+all 128 lanes of its row, the layout of a ``[BP, 128]`` front.  A fetch
+then picks, lane by lane, between slabs by the bits of the lane's index:
+127 full-vreg selects per 128 words and 128 diagonal lanes, no
+cross-lane reduce, no gather, and the words land where the front's XOR
+wants them.  A bucket too wide to keep its slabs in VMEM builds them per
+fetch instead.  Interpret mode runs the same fetch, so the CPU tests
+exercise the program the chip runs.
 
 The sequences arrive as **packed words** (built by the ops wrapper):
 word ``l`` holds characters ``l .. l + C - 1`` at ``char_bits`` bits each,
@@ -81,37 +87,108 @@ _THRESH = NEG // 2
 # The TPU compiler's default scoped-VMEM limit for one kernel.
 SCOPED_VMEM_BYTES = 16 << 20
 
+# Words per group of the extend fetch's select tree (a power of two).
+FETCH_GROUP = 32
 
-def vmem_estimate(block_pairs: int, k_pad: int, n_bt: int,
-                  n_words: int) -> int:
-    """Scoped VMEM bytes a compiled block needs, calibrated on v5e compiles.
 
-    About 4.2 KB per (pair, diagonal lane) for the rings, the [BP, K]
-    fronts and the onehot fetch, plus the double-buffered packed-trace
-    output blocks (``n_bt`` planes of ``n_words`` int32 words).  At 8 pairs
-    per block that admits k_pad <= 384 at every bucket width from 128 to
-    1024, and trace depth up to s_max = 704 at k_pad = 384.
+def vmem_estimate(block_pairs: int, k_pad: int, n_bt: int, n_words: int,
+                  resident_words: int = 0) -> int:
+    """Scoped VMEM bytes a compiled block needs: an upper envelope of the
+    least limit under which v5e compiles take each shape.
+
+    A fixed 1.25 MiB (the fetch's staging and the rest), 192 B per (pair,
+    diagonal lane) for the rings and the [BP, K] fronts, the
+    double-buffered packed-trace output blocks (``n_bt`` planes of
+    ``n_words`` int32 words), and 576 B per (pair, word) that the fetch
+    keeps resident (``resident_words``: both sequences' bucket widths,
+    summed), for their [L, BP, 128] slabs.  At 8 pairs per block the
+    slabs stay resident up to 1,024-word buckets at k_pad 384, trace
+    depth included; wider buckets build them per fetch.
     """
-    return block_pairs * k_pad * (4332 + 8 * n_bt * n_words)
+    return (5 << 18) + block_pairs * (k_pad * (192 + 8 * n_bt * n_words)
+                                      + 576 * resident_words)
 
 
-def _gather_chars(seq, idx):
-    """seq [BP, L], idx [BP, K] -> seq[b, idx[b, k]] as [BP, K].
+def seq_scratch(block_pairs: int, width: int):
+    """VMEM scratch for one sequence's words as the fetch reads them:
+    ``[width, block_pairs, 128]`` int32 (see :func:`_slabs`)."""
+    return pltpu.VMEM((width, block_pairs, 128), jnp.int32)
 
-    idx is pre-clipped here; out-of-range lanes read junk that the caller's
-    validity mask discards.  A compare-and-reduce (TPUs lack per-lane
-    gather), taken 128 diagonal lanes at a time so that its [BP, 128, L]
-    intermediate, not a [BP, K, L] one, sets its scoped VMEM.
-    """
-    L = seq.shape[1]
+
+def _slabs(seq):
+    """seq [BP, n] -> [n, BP, 128]: slab l holds word l of pair b on every
+    lane of row b, laid out like a [BP, 128] front."""
+    BP, n = seq.shape
+    return jnp.broadcast_to(seq.T[:, :, None], (n, BP, 128))
+
+
+def _fetch_words(seq_ref, scratch_ref):
+    """The fetch's source of slabs for one sequence: ``words(l0, n)`` ->
+    slabs l0 .. l0 + n - 1 (l0 a multiple of 128, maybe traced).
+
+    With a scratch (``seq_scratch``) the slabs are built once per grid
+    block and every fetch reads them; without one (a bucket too wide to
+    keep them in VMEM, see :func:`wfa_pallas`) each fetch builds them
+    from the block's words, 128 words at a time."""
+    L = seq_ref.shape[1]
+    if scratch_ref is None:
+        return lambda l0, n: _slabs(seq_ref[:, pl.ds(l0, n)])
+    for l0 in range(0, L, 128):
+        n = min(128, L - l0)
+        scratch_ref[l0:l0 + n] = _slabs(seq_ref[:, l0:l0 + n])
+    return lambda l0, n: scratch_ref.at[pl.ds(l0, n)]
+
+
+def _select_tree(x, idx, bit):
+    """x [n, BP, 128] -> [BP, 128]: on each lane, x[i] where i is that
+    lane's idx read from bit ``bit`` up (the lower bits chose x)."""
+    while x.shape[0] > 1:
+        n = x.shape[0]
+        pairs = x[:n - n % 2].reshape((n // 2, 2) + x.shape[1:])
+        y = jnp.where((idx & (1 << bit)) != 0, pairs[:, 1], pairs[:, 0])
+        # an unpaired last word stands for itself: a lane that reaches it
+        # has the bit clear, since its idx is below L
+        x = y if n % 2 == 0 else jnp.concatenate([y, x[n - 1:]])
+        bit += 1
+    return x[0]
+
+
+def _gather_chars(words, L: int, idx):
+    """words (:func:`_fetch_words`) of a bucket L words wide (L <= 128 or
+    a multiple of 128), idx [BP, K] -> seq[b, clip(idx[b, k], 0, L - 1)]
+    as [BP, K].
+
+    Out-of-range lanes read junk that the caller's validity mask
+    discards.  Per 128 diagonal lanes, a binary select tree over the
+    slabs picks each lane's word by the bits of its index, with no
+    cross-lane reduce and no gather (TPUs lack per-lane gather); the
+    result lands in the front's own layout.  The tree runs
+    ``FETCH_GROUP`` slabs at a time, so a group's words and partial
+    selects stay in vector registers, then over the groups' picks, 127
+    selects per 128 words.  A wider bucket loops over its 128-word
+    chunks, keeping the pick of the chunk each lane's index falls in."""
+    assert L <= 128 or L % 128 == 0, L
     idx_c = jnp.clip(idx, 0, L - 1)
-    K = idx.shape[1]
+    top = (FETCH_GROUP - 1).bit_length()
+
+    def chunk_pick(part, l0, n):
+        chunk = words(l0, n)
+        picks = [_select_tree(chunk[g:g + FETCH_GROUP], part, 0)
+                 for g in range(0, n, FETCH_GROUP)]
+        return _select_tree(jnp.stack(picks), part, top)
+
     parts = []
-    for k0 in range(0, K, 128):
+    for k0 in range(0, idx.shape[1], 128):
         part = idx_c[:, k0:k0 + 128]
-        l_iota = lax.broadcasted_iota(jnp.int32, part.shape + (L,), 2)
-        hit = (l_iota == part[:, :, None])
-        parts.append(jnp.sum(jnp.where(hit, seq[:, None, :], 0), axis=2))
+        if L <= 128:
+            parts.append(chunk_pick(part, 0, L))
+            continue
+        hi = part >> 7
+
+        def step(c, acc, part=part, hi=hi):
+            pick = chunk_pick(part, pl.multiple_of(c * 128, 128), 128)
+            return jnp.where(hi == c, pick, acc)
+        parts.append(lax.fori_loop(0, L // 128, step, jnp.zeros_like(part)))
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
@@ -136,7 +213,7 @@ def _zero_fields(x, char_bits: int):
 
 
 def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
-                 char_bits: int, band: bool):
+                 char_bits: int, band: bool, resident: bool):
     x, o, e = model.x, model.o, model.e
     W = model.window
     affine = model.kind == "affine"
@@ -147,7 +224,9 @@ def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
     def kernel(p_ref, t_ref, pl_ref, tl_ref, out_ref, steps_ref, trips_ref,
                *refs):
         bt_refs = refs[:n_bt]
-        rings = refs[:-1][n_bt:] if band else refs[n_bt:]
+        # [L, BP, 128] word slabs, when they stay resident
+        p_w, t_w = refs[n_bt:n_bt + 2] if resident else (None, None)
+        rings = refs[n_bt + 2 * resident:len(refs) - band]
         off_ref = refs[-1] if band else None  # [W, 1] SMEM row offsets
         if affine:
             m_ring, i_ring, d_ring = rings
@@ -157,8 +236,8 @@ def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
         _, Lt = t_ref.shape
         Kc = m_ring.shape[-1]                # compact (== k_pad unless band)
 
-        pat = p_ref[...]
-        txt = t_ref[...]
+        pat = _fetch_words(p_ref, p_w)
+        txt = _fetch_words(t_ref, t_w)
         plen = pl_ref[...]                   # [BP, 1]
         tlen = tl_ref[...]
         jidx = lax.broadcasted_iota(jnp.int32, (BP, Kc), 1)
@@ -176,14 +255,10 @@ def _make_kernel(model, heur, s_max: int, k_pad: int, trace: bool,
                 v = M - ks
                 can = ((M > _THRESH) & (M >= 0) & (M < tlen)
                        & (v >= 0) & (v < plen))
-                tw = _gather_chars(txt, M)
-                pw = _gather_chars(pat, v)
-                # The onehot fetch's lane reduce leaves the words one per
-                # sublane row, 8 to a vreg.  Selecting on `can`, laid out
-                # like the front (1,024 to a vreg), moves the XOR into the
-                # front's layout once, so the field count and the clamp
-                # below run on that; -1 matches no field.
-                x = jnp.where(can, tw ^ pw, -1)
+                # both words arrive in the front's layout; -1 matches no
+                # field, so lanes that may not extend count none
+                x = jnp.where(can, _gather_chars(txt, Lt, M)
+                              ^ _gather_chars(pat, Lp, v), -1)
                 # matched prefix of the two words, cut at either end
                 run = jnp.minimum(jnp.minimum(tlen - M, plen - v),
                                   _zero_fields(x, char_bits))
@@ -395,8 +470,13 @@ def wfa_pallas(pattern, text, plen, tlen, *, pen, s_max: int,
     band = band_cap is not None and band_cap < k_pad
     Kc = band_cap if band else k_pad
     n_bt = (3 if model.kind == "affine" else 1) if trace else 0
+    NW = n_trace_words(s_max)
+    # keep the fetch's word slabs resident where they fit, else build them
+    # per fetch (the same words, the same picks)
+    resident = (vmem_estimate(BP, k_pad, n_bt, NW, Lp + Lt)
+                <= SCOPED_VMEM_BYTES)
     if not interpret:
-        need = vmem_estimate(BP, k_pad, n_bt, n_trace_words(s_max))
+        need = vmem_estimate(BP, k_pad, n_bt, NW, (Lp + Lt) * resident)
         if need > SCOPED_VMEM_BYTES:
             raise ValueError(
                 f"WFA kernel block too wide for the TPU's scoped VMEM: "
@@ -405,7 +485,7 @@ def wfa_pallas(pattern, text, plen, tlen, *, pen, s_max: int,
                 f"{SCOPED_VMEM_BYTES >> 20} MiB (bucket [{B}, {Lp}]); "
                 f"align this bucket on the 'ring' backend")
     kernel, W, affine = _make_kernel(model, heur, s_max, k_pad, trace,
-                                     char_bits, band)
+                                     char_bits, band, resident)
     grid = (B // BP,)
     n_rings = 3 if affine else 1
 
@@ -413,11 +493,11 @@ def wfa_pallas(pattern, text, plen, tlen, *, pen, s_max: int,
     out_specs = [spec2(1)] * 3
     out_shape = [jax.ShapeDtypeStruct((B, 1), jnp.int32)] * 3
     if trace:
-        NW = n_trace_words(s_max)
         bt_spec = pl.BlockSpec((NW, BP, k_pad), lambda i: (0, i, 0))
         out_specs += [bt_spec] * n_bt
         out_shape += [jax.ShapeDtypeStruct((NW, B, k_pad), jnp.int32)] * n_bt
-    scratch = [pltpu.VMEM((W, BP, Kc), jnp.int32)] * n_rings
+    scratch = [seq_scratch(BP, Lp), seq_scratch(BP, Lt)] if resident else []
+    scratch += [pltpu.VMEM((W, BP, Kc), jnp.int32)] * n_rings
     if band:
         scratch += [pltpu.SMEM((W, 1), jnp.int32)]
     return pl.pallas_call(

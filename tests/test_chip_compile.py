@@ -22,10 +22,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
 from repro.core.backends import get_backend
 from repro.core.engine import AlignmentEngine
 from repro.core.scoring import AdaptiveBand, Edit, GapAffine, GapLinear
+from repro.core.wavefront import n_trace_words
+from repro.kernels.wfa import kernel
 from repro.kernels.wfa import ops as kops
 
 PAIRS, WIDTH = 2048, 128
 AFFINE = GapAffine(4, 6, 2)
+# The least bounds the scoped-VMEM estimate refuses at 8 pairs per block
+# and a 128-wide bucket: k_pad 10,112 in score mode, and at k_pad 384 a
+# trace of 202 words (s_max 3,216).  One step below each is admitted.
+VMEM_K_MAX, VMEM_S_MAX = 5000, 3216
 CHAR_BITS = pytest.mark.parametrize("char_bits", [2, 8, 32])
 
 
@@ -69,11 +75,11 @@ def _bounds(pen, edit_frac, exact):
                                   np.array([104, 100]), exact, pen=pen)
 
 
-def _compile(one_chip, fn, **kw):
+def _compile(one_chip, fn, width=WIDTH, **kw):
     spec = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32,
                                               sharding=one_chip)
     call = jax.jit(functools.partial(fn, interpret=False, **kw))
-    return call.lower(spec((PAIRS, WIDTH)), spec((PAIRS, WIDTH)),
+    return call.lower(spec((PAIRS, width)), spec((PAIRS, width)),
                       spec((PAIRS,)), spec((PAIRS,))).compile().as_text()
 
 
@@ -112,11 +118,34 @@ def test_compacting_band_kernel_compiles(one_chip, no_persistent_cache,
         assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("k_max,s_max,trace", [(256, 64, False),
-                                                (128, 800, True)],
+@pytest.mark.parametrize("width,k_max,resident", [
+    (1024, 63, True), (1024, 191, True), (2048, 63, False)],
+    ids=["1024-words-k_pad-128", "1024-words-k_pad-384",
+         "2048-words-k_pad-128"])
+def test_wide_bucket_score_kernel_compiles(one_chip, no_persistent_cache,
+                                           width, k_max, resident):
+    """Buckets of 1,024 words keep the fetch's word slabs in VMEM; a
+    2,048-word bucket does not fit them and builds them per fetch.
+    Both compile."""
+    k_pad = kops._round_up(2 * k_max + 1, 128)
+    fits = kernel.vmem_estimate(8, k_pad, 0, 0, 2 * width) \
+        <= kernel.SCOPED_VMEM_BYTES
+    assert fits == resident
+    hlo = _compile(one_chip, kops.wfa_align, width=width, pen=AFFINE,
+                   s_max=64, k_max=k_max, char_bits=2)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("k_max,s_max,trace", [(VMEM_K_MAX, 64, False),
+                                                (128, VMEM_S_MAX, True)],
                          ids=["diagonals", "trace-depth"])
 def test_vmem_overflow_is_refused_by_name(one_chip, no_persistent_cache,
                                           k_max, s_max, trace):
+    k_pad = kops._round_up(2 * k_max + 1, 128)
+    n_bt = 3 if trace else 0
+    below = ((k_pad, s_max - 16) if trace else (k_pad - 128, s_max))
+    assert kernel.vmem_estimate(8, below[0], n_bt, n_trace_words(below[1])) \
+        <= kernel.SCOPED_VMEM_BYTES
     fn = kops.wfa_align_trace if trace else kops.wfa_align
     with pytest.raises(ValueError, match="scoped VMEM"):
         _compile(one_chip, fn, pen=AFFINE, s_max=s_max, k_max=k_max)

@@ -171,12 +171,20 @@ def test_ring_entry_points_agree(pen, band):
 # -- the one fetch against the oracle ---------------------------------------
 
 
-@pytest.mark.parametrize("pen", [DEFAULT, Penalties(1, 0, 1)],
-                         ids=["affine", "linear"])
-def test_gather_mode_invariance(pen):
-    """The kernel's one character fetch, the compare-and-reduce the chip
-    runs, returns ``wfa_forward``'s scores at 16 characters per trip and
-    at one."""
+# ``trips``: the kernel's extend trips on the 100 bp, E = 4% batch below
+# (seed 4, 24 pairs, 16 characters per trip).  The count is the
+# algorithm's, so no way of fetching the words may change it.
+@pytest.mark.parametrize("pen,trips", [(DEFAULT, 147),
+                                       (Penalties(1, 0, 1), 71),
+                                       (GapLinear(4, 2), 113), (Edit(), 71)],
+                         ids=["affine", "linear", "gap-linear", "edit"])
+def test_gather_mode_invariance(pen, trips):
+    """The kernel's one character fetch, the one the chip runs, returns
+    ``wfa_forward``'s scores at 16 characters per trip and at one; and on
+    a seeded 100 bp, E = 4% batch the kernel's scores, score steps and
+    packed trace planes equal the ring loop's (``wfa_scores`` /
+    ``wfa_scores_packed``, run per 8-pair block as the kernel's grid runs
+    them), with the extend trips pinned."""
     P, plen, T, tlen, s_max, k_max = _pairs(8, 32, 0.06, 25)
     want = np.asarray(wf.wfa_forward(P, T, plen, tlen, pen=pen, s_max=s_max,
                                      k_max=k_max, keep_history=False).score)
@@ -186,6 +194,39 @@ def test_gather_mode_invariance(pen):
                                         s_max=s_max, k_max=k_max,
                                         char_bits=char_bits))
         np.testing.assert_array_equal(got, want, err_msg=f"{char_bits}")
+
+    P, plen, T, tlen = generate_pairs(
+        ReadPairSpec(n_pairs=24, read_len=100, edit_frac=0.04, seed=4))
+    s_max, k_max = problem_bounds(pen, plen, tlen, 0.04)
+    score, steps, k_trips = kops.wfa_align(P, T, plen, tlen, pen=pen,
+                                           s_max=s_max, k_max=k_max,
+                                           char_bits=2, counts=True)
+    t_score, *planes, t_steps, t_trips = kops.wfa_align_trace(
+        P, T, plen, tlen, pen=pen, s_max=s_max, k_max=k_max, char_bits=2,
+        counts=True)
+    planes = [np.asarray(p) for p in planes if p is not None]
+    c = planes[0].shape[2] // 2
+    ring_steps = 0
+    for b in range(0, len(plen), 8):
+        blk = [jnp.asarray(a[b:b + 8]) for a in (P, T, plen, tlen)]
+        ring = wf.wfa_scores(*blk, pen=pen, s_max=s_max, k_max=k_max)
+        packed = wf.wfa_scores_packed(*blk, pen=pen, s_max=s_max,
+                                      k_max=k_max)
+        np.testing.assert_array_equal(np.asarray(score)[b:b + 8],
+                                      np.asarray(ring.score))
+        np.testing.assert_array_equal(np.asarray(t_score)[b:b + 8],
+                                      np.asarray(packed.score))
+        assert int(ring.n_steps) == int(packed.n_steps)
+        ring_steps += int(ring.n_steps)
+        want_planes = [np.asarray(p) for p in
+                       (packed.m_bt, packed.i_bt, packed.d_bt)
+                       if p is not None]
+        assert len(want_planes) == len(planes)
+        for g, w in zip(planes, want_planes):
+            np.testing.assert_array_equal(
+                g[:, b:b + 8, c - k_max:c + k_max + 1], w)
+    assert int(steps) == int(t_steps) == ring_steps
+    assert int(k_trips) == int(t_trips) == trips
 
 
 # -- engine backend_opts plumbing -------------------------------------------

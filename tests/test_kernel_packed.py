@@ -8,8 +8,11 @@ the scores equal the Gotoh oracle.  The rows put match runs against the word bou
 the sequence ends; lowercase, N and other byte codes run at the 8-bit
 and 32-bit widths only, the widths the session picks for them.
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from repro.core.engine import AlignmentEngine, fetch_char_bits, pack_batch
 from repro.core.gotoh import gotoh_score_vec
@@ -17,7 +20,8 @@ from repro.core.penalties import DEFAULT
 from repro.core.scoring import AdaptiveBand, GapLinear
 from repro.data.reads import ReadPairSpec, generate_pairs
 from repro.kernels.wfa import ops as kops
-from repro.kernels.wfa.kernel import _zero_fields
+from repro.kernels.wfa.kernel import (_fetch_words, _gather_chars,
+                                      _zero_fields, seq_scratch)
 
 S_MAX, K_MAX = 160, 40
 _SUB = {"A": "C", "C": "G", "G": "T", "T": "A"}
@@ -134,6 +138,65 @@ def test_packed_fetch_is_exact(char_bits, pen, output):
     want = _run(batches[0], 32, trace, pen, **kw)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(g, w)
+
+
+def _fetch(words, idx, resident):
+    """The kernel's fetch run alone (interpreted): words [BP, L] int32,
+    idx [BP, K] int32 -> [BP, K], reading slabs kept in a scratch
+    (``resident``) or built per fetch, as a bucket too wide for VMEM
+    does."""
+    BP, L = words.shape
+
+    def body(w_ref, i_ref, o_ref, *scratch):
+        src = _fetch_words(w_ref, scratch[0] if resident else None)
+        o_ref[...] = _gather_chars(src, L, i_ref[...])
+
+    return pl.pallas_call(
+        body, out_shape=jax.ShapeDtypeStruct(idx.shape, jnp.int32),
+        scratch_shapes=[seq_scratch(BP, L)] if resident else [],
+        interpret=True)(jnp.asarray(words), jnp.asarray(idx))
+
+
+def _check_fetch(char_bits, k_pad, width, resident):
+    rng = np.random.default_rng(char_bits * 1000 + k_pad + width)
+    BP = 8
+    if char_bits == 32:
+        codes = rng.integers(-2**31, 2**31, size=(BP, width), dtype=np.int64)
+        codes = codes.astype(np.int32)
+    else:
+        alpha = b"ACGT" if char_bits == 2 else bytes(range(256))
+        codes = rng.choice(np.frombuffer(alpha, np.uint8),
+                           size=(BP, width)).astype(np.int32)
+    words = np.asarray(kops.pack_words(codes, char_bits))
+    idx = rng.integers(-2 * width, 2 * width, size=(BP, k_pad))
+    edges = [-1, 0, 1, width - 2, width - 1, width, width + 1, -width,
+             2**30, -2**30]
+    idx[:, :len(edges)] = edges
+    idx[1::2, -len(edges):] = edges
+    idx = idx.astype(np.int32)
+    got = np.asarray(_fetch(words, idx, resident))
+    want = np.take_along_axis(words, np.clip(idx, 0, width - 1), axis=1)
+    np.testing.assert_array_equal(got, want)
+    assert (idx < 0).any() and (idx >= width).any()
+
+
+@pytest.mark.parametrize("width", [128, 512])
+@pytest.mark.parametrize("k_pad", [128, 256, 384])
+@pytest.mark.parametrize("char_bits", [2, 8, 32])
+def test_fetch_reads_the_clipped_word(char_bits, k_pad, width):
+    """The fetch returns ``seq[b, clip(idx[b, k], 0, L - 1)]`` bit for bit,
+    at every fetch width's words (the sign bit set or not), on every
+    128-lane chunk of the front, and for lanes below 0 and at or past
+    the bucket's end."""
+    _check_fetch(char_bits, k_pad, width, resident=True)
+
+
+@pytest.mark.parametrize("width", [128, 640])
+@pytest.mark.parametrize("char_bits", [2, 32])
+def test_streamed_fetch_reads_the_clipped_word(char_bits, width):
+    """The same words when the slabs are built per fetch, as for buckets
+    too wide to keep them in VMEM."""
+    _check_fetch(char_bits, 256, width, resident=False)
 
 
 def test_every_batch_here_takes_its_width():
